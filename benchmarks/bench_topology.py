@@ -38,11 +38,7 @@ from __future__ import annotations
 
 import dataclasses
 import json
-import os
 import random
-import subprocess
-import sys
-import tempfile
 import time
 
 import numpy as np
@@ -281,53 +277,6 @@ def _gold_protocol_speedup(rows: list, inst) -> dict:
     }
 
 
-_WARMUP_SNIPPET = """\
-import random
-from repro.core import paillier as gold, paillier_batch as pb
-key = gold.keygen({bits}, random.Random(0))
-w = pb.warmup(pb.make_batch_key(key), (8, (1, 8, 8)))
-print(w["seconds"])
-"""
-
-
-def _compile_cache_cold_warm(rows: list) -> dict:
-    """Cold-vs-warm PROCESS warmup_s through the persistent XLA cache.
-
-    Two fresh subprocesses run the same ``paillier_batch.warmup`` with
-    ``REPRO_COMPILE_CACHE`` pointing at a private empty directory: the
-    first pays the full lowering (and populates the cache), the second
-    deserializes.  The ratio is what a production relaunch saves
-    (ROADMAP PR-3 follow-up; see ``repro.kernels.compile_cache``).
-    """
-    env = dict(os.environ)
-    src = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "src")
-    env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
-    env.pop("REPRO_NO_COMPILE_CACHE", None)
-    out = {}
-    with tempfile.TemporaryDirectory(prefix="repro_jax_cache_") as d:
-        env["REPRO_COMPILE_CACHE"] = d
-        for label in ("cold", "warm"):
-            proc = subprocess.run(
-                [sys.executable, "-c",
-                 _WARMUP_SNIPPET.format(bits=GOLD_KEY_BITS)],
-                env=env, capture_output=True, text=True, timeout=600)
-            if proc.returncode != 0:
-                out[f"{label}_process_warmup_s"] = None
-                out["error"] = proc.stderr.strip()[-500:]
-                break
-            out[f"{label}_process_warmup_s"] = \
-                float(proc.stdout.strip().splitlines()[-1])
-        out["cache_entries"] = len(os.listdir(d))
-    cold = out.get("cold_process_warmup_s")
-    warm = out.get("warm_process_warmup_s")
-    if cold and warm:
-        out["speedup_cold_over_warm"] = cold / warm
-        emit(rows, "topo_compile_cache_warm_process", warm,
-             derived=f"cold_s={cold:.3f};speedup={cold / warm:.2f}")
-    return out
-
-
 def run(rows: list) -> None:
     inst = make_lasso(M, N, sparsity=0.1, noise=0.01, seed=3)
     results, targets = _sweep(rows, inst, EDGE_COUNTS, TOPOLOGIES, ITERS)
@@ -344,7 +293,6 @@ def run(rows: list) -> None:
         "ops": _op_micro(rows),
         "reduce_impl": _reduce_impl_micro(rows),
         "protocol_star": _gold_protocol_speedup(rows, inst_l),
-        "compile_cache": _compile_cache_cold_warm(rows),
         "note": ("speedup_vs_scalar < 1 means the scalar Python-int path "
                  "is faster on this device (typical on CPU, where the "
                  "adaptive dispatcher keeps scalar gold); the batched path "

@@ -2,6 +2,8 @@
 
 ``python -m benchmarks.run [--only fig6,tab2,...]`` prints
 ``name,us_per_call,derived`` CSV rows (and tees them per-bench as it goes).
+A bench that raises leaves a ``<key>_ERROR`` row, the others still run,
+and the process exits nonzero.
 ``--help`` / ``--list`` show every registered bench; benchmarks/README.md
 documents what each one reproduces, its expected runtime and its output
 schema.
@@ -79,6 +81,7 @@ def main() -> None:
     import inspect
     rows: list[str] = ["name,us_per_call,derived"]
     print(rows[0])
+    failed = []
     for key, mod_name, _ in BENCHES:
         if want and key not in want:
             continue
@@ -90,12 +93,15 @@ def main() -> None:
             kw["smoke"] = True
         try:
             mod.run(rows, **kw)
-        except Exception as e:  # noqa: BLE001
+        except Exception as e:  # noqa: BLE001 — record it, run the rest
             rows.append(f"{key}_ERROR,0,{type(e).__name__}:{e}")
+            failed.append(key)
         for r in rows[before:]:
             print(r, flush=True)
         _ledger_rows(key, rows[before:])
         print(f"# {key} done in {time.time()-t0:.1f}s", file=sys.stderr)
+    if failed:
+        sys.exit(f"benches raised: {', '.join(failed)}")
 
 
 def _ledger_rows(bench: str, rows: list[str]) -> None:

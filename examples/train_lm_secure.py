@@ -27,6 +27,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import get_config, get_reduced
 from repro.core.secure_agg import CompressionConfig
 from repro.data.pipeline import TokenPipeline
+from repro.launch.mesh import make_mesh
 from repro.train import checkpoint as ckpt
 from repro.train import loop as loop_mod
 from repro.train.optimizer import OptConfig
@@ -41,8 +42,7 @@ steps = args.steps or (300 if args.full else 60)
 batch, seq = (8, 256) if args.full else (4, 32)
 
 n_dev = jax.device_count()
-mesh = jax.make_mesh((n_dev,), ("data",),
-                     axis_types=(jax.sharding.AxisType.Auto,))
+mesh = make_mesh((n_dev,), ("data",))
 comp = CompressionConfig(bits=8, enabled=n_dev > 1, error_feedback=True)
 opt = OptConfig(lr=3e-3, warmup_steps=steps // 10, total_steps=steps)
 

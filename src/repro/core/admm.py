@@ -22,7 +22,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 jax.config.update("jax_enable_x64", True)
 
@@ -192,7 +191,7 @@ def make_spmd_admm(mesh, cfg: ADMMConfig, K: int, axis: str = "data"):
         obj = 0.5 * jnp.sum((y - res) ** 2) + cfg.lam * l1
         return x_new, z_new, v_new, obj
 
-    smapped = shard_map(
+    smapped = jax.shard_map(
         step_local, mesh=mesh,
         in_specs=(P(None, axis), P(), P(axis), P(axis), P(axis)),
         out_specs=(P(axis), P(axis), P(axis), P()),

@@ -504,9 +504,9 @@ def warmup(bk: BatchKey, shapes: Sequence,
     traffic.  Returns ``{"calls", "seconds"}`` telemetry.
 
     Compiles persist across PROCESSES too: the persistent XLA compile
-    cache (``kernels.compile_cache``, ``~/.cache/repro/jax_cache``,
-    opt-out ``REPRO_NO_COMPILE_CACHE=1``) is enabled here, so a warm
-    cache turns the lowering work below into deserialization.
+    cache (``kernels.compile_cache``: ``$JAX_COMPILATION_CACHE_DIR``, else
+    ``<repo>/.jax_cache``) is enabled here, so a warm cache turns the
+    lowering work below into deserialization.
     """
     from ..kernels import compile_cache
     compile_cache.enable()

@@ -25,7 +25,8 @@ master knows B@1 row sums from the Initialization phase and u1+u2 = z - v).
 
 int64 guard: the integer chain value is bounded by ~2 N Delta^2; keep
 Delta <= sqrt(2^62 / (2 N)) for the in-JAX path (DEFAULT_DELTA below), and use
-the Python-int gold path for the paper's Delta = 1e15 regime.
+the Python-int gold path for the paper's Delta = 1e15 regime, where Gamma_1
+codes are Python ints too.
 """
 from __future__ import annotations
 
@@ -67,10 +68,18 @@ def gamma2(u, spec: QuantSpec):
 
 
 def gamma1(u, spec: QuantSpec):
-    """Gamma_1: reals -> {0..Delta^2/s} (eq. 14a), int64."""
+    """Gamma_1: reals -> {0..Delta^2/s} (eq. 14a).
+
+    int64 while the code range Delta^2/s fits it; above that (the paper's
+    Delta = 1e15 gives ~3e28) an object array of exact Python ints, since
+    a cast to int64 would saturate every code."""
     q = jnp.round(spec.delta ** 2 * (jnp.asarray(u, jnp.float64) - spec.zmin)
                   / spec.span ** 2)
-    return q.astype(jnp.int64)
+    if spec.delta ** 2 / spec.span < 2.0 ** 62:
+        return q.astype(jnp.int64)
+    q = np.asarray(q)
+    return np.array([int(v) for v in q.reshape(-1)],
+                    dtype=object).reshape(q.shape)
 
 
 def inv_gamma2(q, spec: QuantSpec):

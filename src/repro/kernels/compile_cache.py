@@ -1,64 +1,61 @@
 """Persistent XLA compilation cache for the limb kernels.
 
-``paillier_batch.warmup`` moved the batched-path compiles out of the
-measured protocol path, but each PROCESS still paid them once: the jit
-cache lives in process memory.  Pointing JAX's persistent compilation
-cache at a directory under ``~/.cache/repro/`` makes the warmup itself
-amortize across processes — the second ``edge_sim`` / benchmark / CI run
-deserializes executables instead of re-lowering them.
+Each process pays the batched-path compiles once, since the jit caches
+live in process memory; JAX's persistent compilation cache lets the next
+process deserialize those executables instead of lowering them again.
 
-Opt-out with ``REPRO_NO_COMPILE_CACHE=1`` (e.g. when benchmarking true
-cold-compile numbers); relocate with ``REPRO_COMPILE_CACHE=/path``.
-:func:`enable` is idempotent and never raises — a JAX build without the
-persistent-cache config knobs simply runs uncached, exactly as before.
-Hooked into :func:`repro.core.paillier_batch.warmup` and
-``repro.runtime.dispatch.calibrate`` so every warmed entry point gets it;
-``benchmarks/bench_topology.py`` records the measured cold-vs-warm
-process ``warmup_s`` under ``gold_fastpath.compile_cache``.
+Where the cache lives:
+
+* ``JAX_COMPILATION_CACHE_DIR``, when set, is used as it is; no other
+  directory is set in code;
+* otherwise one fixed directory inside the checkout, ``<repo>/.jax_cache``
+  (git-ignored).  The path is part of what makes a later run hit, so it
+  never carries a temporary name, a process id or a time.
+
+:func:`enable` is idempotent.  The entry points call it at start-up
+(``chip_smoke.py``, ``repro.launch.edge_sim``, ``repro.launch.serve_sim``),
+and so do :func:`repro.core.paillier_batch.warmup` and
+``repro.runtime.dispatch.calibrate``.
 """
 from __future__ import annotations
 
 import os
 
-ENV_DIR = "REPRO_COMPILE_CACHE"
-ENV_OFF = "REPRO_NO_COMPILE_CACHE"
-DEFAULT_DIR = "~/.cache/repro/jax_cache"
+ENV_DIR = "JAX_COMPILATION_CACHE_DIR"
+DEFAULT_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))), ".jax_cache")
 
 _state: dict = {"enabled": None}
 
 
 def cache_dir() -> str:
-    return os.path.expanduser(os.environ.get(ENV_DIR, DEFAULT_DIR))
+    """The directory :func:`enable` uses: ``$JAX_COMPILATION_CACHE_DIR``,
+    else one a host application already gave JAX, else the checkout's."""
+    import jax
+    return (os.environ.get(ENV_DIR) or jax.config.jax_compilation_cache_dir
+            or DEFAULT_DIR)
 
 
-def enable(path: str | None = None) -> str | None:
-    """Point jax's persistent compilation cache at ``path`` (default
-    ``$REPRO_COMPILE_CACHE`` or ``~/.cache/repro/jax_cache``).
+def enable() -> str | None:
+    """Point JAX's persistent compilation cache at :func:`cache_dir`.
 
-    Returns the directory in use, or ``None`` when disabled (opt-out env
-    var set, or the running jax lacks the config knobs).  Safe to call
-    repeatedly; only the first call with a given path reconfigures.
+    Returns the directory in use, or ``None`` when it cannot be created
+    (a read-only checkout then runs uncached).  Safe to call repeatedly.
     """
-    if os.environ.get(ENV_OFF):
-        return None
-    path = os.path.expanduser(path) if path else cache_dir()
+    import jax
+    path = cache_dir()
     if _state["enabled"] == path:
         return path
     try:
-        import jax
-        # a host application that already configured its own persistent
-        # cache keeps it — we only fill the knob when nobody has
-        existing = jax.config.jax_compilation_cache_dir
-        if existing and _state["enabled"] is None:
-            return existing
         os.makedirs(path, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", path)
-        # cache every kernel regardless of size/compile time: the batched
-        # CRT executables are individually small but numerous
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    except Exception:   # noqa: BLE001 — older jax / read-only FS: run uncached
+    except OSError:
         return None
+    jax.config.update("jax_compilation_cache_dir", path)
+    # cache every kernel regardless of size/compile time: the batched
+    # CRT executables are individually small but numerous
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     _state["enabled"] = path
     return path
 

@@ -43,7 +43,8 @@ def mulmod_pallas(a8: jax.Array, b8: jax.Array, m8: jax.Array, mu8: jax.Array,
     """(B, L) x (B, L) mod m -> (B, L). Batch must be a block_b multiple.
 
     ``m8``: (1, L); ``mu8``: (1, Lmu >= L+1) = floor(256^{2L}/m).
-    ``interpret=True`` validates on CPU; on TPU pass interpret=False.
+    ``interpret=True`` validates on CPU.  ``interpret=False`` does not
+    lower for the TPU yet (the limb helpers' ``dynamic_slice``).
     """
     bsz, L = a8.shape
     assert bsz % block_b == 0, "pad batch to a block multiple (ops.py does)"

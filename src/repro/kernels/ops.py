@@ -6,8 +6,10 @@ pad the batch to block multiples, dispatch to a backend and convert back.
 
 Backends:
   * ``ref``    — kernels/ref.py jnp oracle (compiled XLA; the fast CPU path)
-  * ``pallas`` — the Pallas kernels; ``interpret=True`` automatically when
-                 running on CPU (this container), compiled Mosaic on TPU.
+  * ``pallas`` — the Pallas kernels, in interpret mode on the CPU only.
+                 They do not lower for the TPU yet: the limb helpers'
+                 ``fori_loop`` + ``dynamic_slice`` have no Pallas TPU
+                 lowering (tests/test_tpu_compile.py pins this).
 
 Reduction (``REPRO_REDUCE_IMPL``, read per call):
   * ``montgomery`` (default) — REDC ladders from kernels/montgomery.py for

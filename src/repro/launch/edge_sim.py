@@ -29,6 +29,7 @@ from repro.core import protocol
 from repro.core.churn import ChurnSchedule
 from repro.core.quantization import QuantSpec
 from repro.data.synthetic import make_lasso
+from repro.kernels import compile_cache
 from repro.obs import chrome_trace, trace as trace_mod
 from repro.runtime import LinkModel, topology as topo_mod
 from repro.runtime.runner import run_on_runtime
@@ -106,6 +107,7 @@ def parse_churn(spec: str, K: int, iters: int, seed: int) -> ChurnSchedule:
 
 def main(argv=None) -> dict:
     args = build_parser().parse_args(argv)
+    compile_cache.enable()
     K = args.edges
     N = K * args.block
     M = max(N // 2, 8)

@@ -10,14 +10,14 @@ import jax
 
 
 def make_mesh(shape, axes):
-    """``jax.make_mesh`` with Auto axis types where the installed jax has
-    them (``jax.sharding.AxisType`` landed after 0.4.37); plain mesh
-    otherwise — older jax treats every axis as Auto already."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with every axis ``Auto``.
+
+    Since jax 0.9 a bare ``jax.make_mesh`` gives ``Explicit`` axes, under
+    which the models' embedding gathers raise ``ShardingTypeError``; every
+    mesh in the repo is built here so the partitioner keeps deciding the
+    layouts."""
     return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def kernel_mesh():

@@ -27,6 +27,7 @@ import time
 from repro import workloads
 from repro.core import protocol
 from repro.data.synthetic import make_lasso
+from repro.kernels import compile_cache
 from repro.obs import chrome_trace, trace as trace_mod
 from repro.serve.protocol_engine import ADMISSIONS, ProtocolEngine, \
     tune_admission
@@ -78,6 +79,7 @@ def _tenant_case(name: str, M: int, N: int, K: int, iters: int, seed: int):
 
 def main(argv=None) -> dict:
     args = build_parser().parse_args(argv)
+    compile_cache.enable()
     K = args.edges
     N = K * args.block
     M = max(N // 2, 8)

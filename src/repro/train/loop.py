@@ -16,7 +16,6 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from . import optimizer as opt_mod
 from ..core import secure_agg
@@ -108,11 +107,11 @@ def make_dp_compressed_step(cfg, opt_cfg: opt_mod.OptConfig, mesh,
         return params, opt_state, residuals, loss, om["grad_norm"]
 
     p_rep = P()
-    smapped = shard_map(
+    smapped = jax.shard_map(
         local_step, mesh=mesh,
         in_specs=(p_rep, p_rep, p_rep, P(axis)),
         out_specs=(p_rep, p_rep, p_rep, p_rep, p_rep),
-        check_rep=False,
+        check_vma=False,
     )
 
     @jax.jit

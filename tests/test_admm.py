@@ -83,11 +83,12 @@ def test_spmd_matches_blocked(subproc):
         import numpy as np, jax, jax.numpy as jnp
         from repro.core import admm
         from repro.data.synthetic import make_lasso
+        from repro.launch.mesh import make_mesh
         inst = make_lasso(40, 160, 0.05, 0.01, seed=1)
         cfg = admm.ADMMConfig(lam=0.05, iters=100)
         x_ref, _ = admm.distributed_admm(jnp.asarray(inst.A),
                                          jnp.asarray(inst.y), 4, cfg)
-        mesh = jax.make_mesh((4,), ("data",))
+        mesh = make_mesh((4,), ("data",))
         run = admm.make_spmd_admm(mesh, cfg, 4)
         with mesh:
             x, objs = run(jnp.asarray(inst.A), jnp.asarray(inst.y))

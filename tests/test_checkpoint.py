@@ -58,17 +58,18 @@ def test_elastic_restore_across_mesh_sizes(subproc, tmp_path):
     subproc(f"""
         import numpy as np, jax, jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
+        from repro.launch.mesh import make_mesh
         from repro.train import checkpoint as ckpt
         from repro.train.fault import elastic_restore
 
         tree = {{"w": jnp.arange(64.0).reshape(8, 8)}}
-        mesh4 = jax.make_mesh((4,), ("data",))
+        mesh4 = make_mesh((4,), ("data",))
         sh4 = NamedSharding(mesh4, P("data"))
         tree4 = {{"w": jax.device_put(tree["w"], sh4)}}
         ckpt.save(r"{tmp_path}", 3, tree4)
 
         # "failure": only 2 devices survive
-        mesh2 = jax.make_mesh((2,), ("data",))
+        mesh2 = make_mesh((2,), ("data",))
         got, _ = elastic_restore(r"{tmp_path}", jax.eval_shape(lambda: tree),
                                  mesh2, {{"w": P("data")}})
         assert np.array_equal(np.asarray(got["w"]), np.asarray(tree["w"]))

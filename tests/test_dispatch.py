@@ -2,6 +2,7 @@
 cost-table routing, cross-representation bit-exactness, batched-launch
 equivalence."""
 import json
+import os
 import random
 
 import numpy as np
@@ -64,62 +65,94 @@ def test_calibrate_writes_and_reuses_cache(tmp_path, monkeypatch):
     assert sorted(calls) == ["gold", "plain"]
 
 
-def test_compile_cache_enable_and_opt_out(tmp_path, monkeypatch):
-    """ROADMAP follow-up: the persistent XLA compile cache points at a
-    ``~/.cache/repro`` directory (so warmup amortizes across PROCESSES),
-    is idempotent, honors the env overrides, and can be opted out."""
+@pytest.fixture
+def fresh_compile_cache(monkeypatch):
+    """A process that has configured no persistent cache yet; the jax
+    config and the module state come back afterwards."""
     import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
     from repro.kernels import compile_cache
     prev = jax.config.jax_compilation_cache_dir
-    prev_state = dict(compile_cache._state)
-    try:
-        # simulate a fresh process: nothing configured yet
-        compile_cache._state["enabled"] = None
-        jax.config.update("jax_compilation_cache_dir", None)
-        d = str(tmp_path / "jx")
-        monkeypatch.setenv(compile_cache.ENV_DIR, d)
-        monkeypatch.delenv(compile_cache.ENV_OFF, raising=False)
-        assert compile_cache.enable() == d
-        assert jax.config.jax_compilation_cache_dir == d
-        assert compile_cache.enable() == d          # idempotent re-enable
-        # a HOST-configured dir (set by someone else while we think we
-        # configured nothing) is respected, not overwritten
-        host = str(tmp_path / "host")
-        jax.config.update("jax_compilation_cache_dir", host)
-        compile_cache._state["enabled"] = None
-        assert compile_cache.enable() == host
-        assert jax.config.jax_compilation_cache_dir == host
-        # opt-out: no reconfiguration happens
-        compile_cache._state["enabled"] = None
-        monkeypatch.setenv(compile_cache.ENV_OFF, "1")
-        assert compile_cache.enable() is None
-    finally:
-        compile_cache._state.update(prev_state)
-        jax.config.update("jax_compilation_cache_dir", prev)
+    monkeypatch.setitem(compile_cache._state, "enabled", None)
+    monkeypatch.delenv(compile_cache.ENV_DIR, raising=False)
+    jax.config.update("jax_compilation_cache_dir", None)
+    yield compile_cache
+    jax.config.update("jax_compilation_cache_dir", prev)
+    cc.reset_cache()
 
 
-def test_warmup_enables_compile_cache(tmp_path, monkeypatch):
+def test_compile_cache_enable_and_opt_out(tmp_path, monkeypatch,
+                                          fresh_compile_cache):
+    """``JAX_COMPILATION_CACHE_DIR`` is used as given, re-enabling is
+    idempotent, a directory a host application already gave JAX is kept,
+    and a directory that cannot be created leaves the run uncached."""
+    import jax
+    compile_cache = fresh_compile_cache
+    d = str(tmp_path / "jx")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", d)
+    assert compile_cache.enable() == d
+    assert jax.config.jax_compilation_cache_dir == d
+    assert compile_cache.enable() == d          # idempotent re-enable
+    assert compile_cache.stats()["dir"] == d
+    # a HOST-configured dir (set by someone else while we think we
+    # configured nothing) is respected, not overwritten
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    host = str(tmp_path / "host")
+    jax.config.update("jax_compilation_cache_dir", host)
+    compile_cache._state["enabled"] = None
+    assert compile_cache.enable() == host
+    assert jax.config.jax_compilation_cache_dir == host
+    # an uncreatable dir: no reconfiguration, the run goes uncached
+    (tmp_path / "file").write_text("")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR",
+                       str(tmp_path / "file" / "sub"))
+    compile_cache._state["enabled"] = None
+    assert compile_cache.enable() is None
+    assert jax.config.jax_compilation_cache_dir == host
+
+
+def test_compile_cache_default_is_fixed_in_checkout(fresh_compile_cache):
+    """Without the variable the cache sits at one fixed, git-ignored path
+    inside the checkout — no home directory, temp name, pid or time."""
+    import jax
+    compile_cache = fresh_compile_cache
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    want = os.path.join(repo, ".jax_cache")
+    assert compile_cache.DEFAULT_DIR == want
+    assert compile_cache.enable() == want
+    assert jax.config.jax_compilation_cache_dir == want
+    with open(os.path.join(repo, ".gitignore")) as f:
+        assert ".jax_cache/" in f.read().split()
+
+
+def test_warmup_enables_compile_cache(tmp_path, monkeypatch,
+                                      fresh_compile_cache):
     """paillier_batch.warmup switches the persistent cache on, so every
     warmed entry point (dispatch.calibrate's warm_key hook, the benches)
     persists its compiles."""
     import jax
     from repro.core import paillier_batch as pb
-    from repro.kernels import compile_cache
-    prev = jax.config.jax_compilation_cache_dir
-    prev_state = dict(compile_cache._state)
-    try:
-        compile_cache._state["enabled"] = None
-        jax.config.update("jax_compilation_cache_dir", None)
-        d = str(tmp_path / "jx2")
-        monkeypatch.setenv(compile_cache.ENV_DIR, d)
-        monkeypatch.delenv(compile_cache.ENV_OFF, raising=False)
-        key = gold.keygen(128, random.Random(3))
-        w = pb.warmup(pb.make_batch_key(key), (8,))
-        assert w["calls"] == 3
+    d = str(tmp_path / "jx2")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", d)
+    key = gold.keygen(128, random.Random(3))
+    w = pb.warmup(pb.make_batch_key(key), (8,))
+    assert w["calls"] == 3
+    assert jax.config.jax_compilation_cache_dir == d
+
+
+def test_entry_points_enable_compile_cache(tmp_path, monkeypatch,
+                                           fresh_compile_cache):
+    """edge_sim and serve_sim turn the cache on before any protocol work."""
+    import jax
+    from repro.launch import edge_sim, serve_sim
+    for i, mod in enumerate((edge_sim, serve_sim)):
+        d = str(tmp_path / f"entry{i}")
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", d)
+        fresh_compile_cache._state["enabled"] = None
+        args = (["--edges", "2", "--iters", "1"] if mod is edge_sim
+                else ["--tenants", "1", "--cipher", "plain", "--iters", "1"])
+        mod.main(args)
         assert jax.config.jax_compilation_cache_dir == d
-    finally:
-        compile_cache._state.update(prev_state)
-        jax.config.update("jax_compilation_cache_dir", prev)
 
 
 def test_lookup_nearest_entry():

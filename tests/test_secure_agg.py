@@ -67,11 +67,11 @@ def _ef_step_fn(bits: int):
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from repro.launch.mesh import make_mesh
 
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     cfg = secure_agg.CompressionConfig(bits=bits, error_feedback=True)
-    f = shard_map(
+    f = jax.shard_map(
         lambda g, r: tuple(
             x[None] for x in secure_agg.compress_tree_psum(
                 g[0], "data", cfg, residuals=r[0])),
@@ -124,12 +124,12 @@ def test_compressed_psum_exact_sum_property(subproc):
     subproc("""
         import numpy as np, jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
         from repro.core import secure_agg
-        mesh = jax.make_mesh((4,), ("data",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4,), ("data",))
         g = np.random.default_rng(0).normal(0, 1, (4, 128)).astype(np.float32)
         for bits, tol in ((8, 2e-2), (16, 1e-4)):
-            f = shard_map(lambda x: secure_agg.compressed_psum(
+            f = jax.shard_map(lambda x: secure_agg.compressed_psum(
                               x[0], "data", bits=bits)[None],
                           mesh=mesh, in_specs=P("data", None),
                           out_specs=P("data", None))
@@ -147,12 +147,13 @@ def test_error_feedback_converges(subproc):
         import numpy as np, jax, jax.numpy as jnp
         from repro.configs import get_reduced
         from repro.core.secure_agg import CompressionConfig
+        from repro.launch.mesh import make_mesh
         from repro.train import loop as loop_mod
         from repro.train.optimizer import OptConfig
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         cfg = get_reduced("yi_9b")
-        mesh = jax.make_mesh((4,), ("data",))
+        mesh = make_mesh((4,), ("data",))
         comp = CompressionConfig(bits=8, enabled=True, error_feedback=True)
         step = loop_mod.make_dp_compressed_step(
             cfg, OptConfig(lr=5e-3, warmup_steps=1, total_steps=20),

@@ -25,6 +25,7 @@ def test_sharded_train_step_matches_unsharded(subproc):
         import numpy as np, jax, jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.configs import get_reduced
+        from repro.launch.mesh import make_mesh
         from repro.models import registry
         from repro.train import loop as loop_mod
         from repro.train.optimizer import OptConfig
@@ -44,7 +45,7 @@ def test_sharded_train_step_matches_unsharded(subproc):
         s_ref, m_ref = jax.jit(step)(state, batch)
 
         # 2x2 mesh pjit
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        mesh = make_mesh((2, 2), ("data", "model"))
         mesh_shape = {"data": 2, "model": 2}
         p_spec = registry.param_pspecs(cfg, state["params"], mesh_shape)
         st_spec = {"params": p_spec,
@@ -75,11 +76,12 @@ def test_moe_expert_parallel_lowers(subproc):
         import numpy as np, jax, jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.configs import get_reduced
+        from repro.launch.mesh import make_mesh
         from repro.models import registry
         cfg = get_reduced("qwen2_moe_a27b")
         m = registry.get_model(cfg)
         params = m.init(cfg, jax.random.PRNGKey(0))
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         specs = registry.param_pspecs(cfg, params, {"data": 2, "model": 4})
         params = jax.tree.map(
             lambda x, s: jax.device_put(x, NamedSharding(mesh, s)),
