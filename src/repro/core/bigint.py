@@ -22,6 +22,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from ..obs.trace import span
+
 jax.config.update("jax_enable_x64", True)
 
 LIMB_BITS = 16
@@ -54,23 +56,24 @@ def from_ints(xs, n_limbs: int) -> np.ndarray:
     Python shifting at protocol batch sizes, with identical semantics
     (including the B=0 case and :func:`from_int`'s range errors).
     """
-    xs = [int(x) for x in xs]
-    if not xs:
-        return np.zeros((0, n_limbs), dtype=np.int32)
-    nbytes = 2 * n_limbs
-    try:
-        buf = b"".join(x.to_bytes(nbytes, "little") for x in xs)
-    except OverflowError:
-        for x in xs:
-            if x < 0:
-                raise ValueError(
-                    "bigint limbs encode nonnegative integers only") from None
-            if x >> (LIMB_BITS * n_limbs):
-                raise ValueError(f"{x.bit_length()}-bit value does not fit "
-                                 f"{n_limbs} limbs") from None
-        raise
-    out = np.frombuffer(buf, dtype="<u2").astype(np.int32)
-    return out.reshape(len(xs), n_limbs)
+    with span("host:to_limbs"):
+        xs = [int(x) for x in xs]
+        if not xs:
+            return np.zeros((0, n_limbs), dtype=np.int32)
+        nbytes = 2 * n_limbs
+        try:
+            buf = b"".join(x.to_bytes(nbytes, "little") for x in xs)
+        except OverflowError:
+            for x in xs:
+                if x < 0:
+                    raise ValueError("bigint limbs encode nonnegative "
+                                     "integers only") from None
+                if x >> (LIMB_BITS * n_limbs):
+                    raise ValueError(f"{x.bit_length()}-bit value does not "
+                                     f"fit {n_limbs} limbs") from None
+            raise
+        out = np.frombuffer(buf, dtype="<u2").astype(np.int32)
+        return out.reshape(len(xs), n_limbs)
 
 
 def to_int(limbs) -> int:
@@ -90,16 +93,27 @@ def to_ints(limbs) -> list:
     with a single ``int.from_bytes`` (limbs are always normalized to
     [0, 2^16) by ``carry_normalize``, which this relies on).
     """
-    arr = np.asarray(limbs)
-    flat = arr.reshape(-1, arr.shape[-1])
-    if flat.shape[0] == 0:
-        return []
-    if flat.dtype == object:
-        return [to_int(row) for row in flat]
-    buf = np.ascontiguousarray(flat.astype("<u2")).tobytes()
-    nbytes = 2 * flat.shape[1]
-    return [int.from_bytes(buf[i * nbytes:(i + 1) * nbytes], "little")
-            for i in range(flat.shape[0])]
+    arr = fetch(limbs)
+    with span("host:to_ints"):
+        flat = arr.reshape(-1, arr.shape[-1])
+        if flat.shape[0] == 0:
+            return []
+        if flat.dtype == object:
+            return [to_int(row) for row in flat]
+        buf = np.ascontiguousarray(flat.astype("<u2")).tobytes()
+        nbytes = 2 * flat.shape[1]
+        return [int.from_bytes(buf[i * nbytes:(i + 1) * nbytes], "little")
+                for i in range(flat.shape[0])]
+
+
+def fetch(x) -> np.ndarray:
+    """``np.asarray(x)``; a device array's copy to the host, which waits
+    for the programs that make it, gets a ``host:fetch`` span of its own
+    so that no host-path span covers a wait for the device."""
+    if isinstance(x, jax.Array):
+        with span("host:fetch"):
+            return np.asarray(x)
+    return np.asarray(x)
 
 
 def barrett_mu(m: int, n_limbs: int) -> np.ndarray:

@@ -95,7 +95,7 @@ class CipherTensor:
         """Materialize (and cache) the batch as Python ints."""
         if self._ints is None:
             CONVERSIONS["to_ints"] += 1
-            self._ints = bi.to_ints(np.asarray(self.limbs))
+            self._ints = bi.to_ints(self.limbs)
         return self._ints
 
     def __iter__(self):

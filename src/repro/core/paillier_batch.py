@@ -55,6 +55,7 @@ from . import bigint as bi
 from . import paillier as gold
 from . import paillier_vec as pv
 from .cipher_tensor import CipherTensor
+from ..obs.trace import span
 from ..kernels import ops
 
 # Below this batch size the per-launch overhead dominates and callers keep
@@ -380,7 +381,8 @@ def dec_vec(bk: BatchKey, cs,
                                            backend=backend, fixed=True))
     else:
         x = modexp_crt_vec(bk, cs, key.lam, backend=backend, fixed=True)
-    return [(xi - 1) // key.n * key.mu % key.n for xi in x]
+    with span("host:dec_finish"):
+        return [(xi - 1) // key.n * key.mu % key.n for xi in x]
 
 
 def matvec_many(bk: BatchKey, Ks, cs_list: Sequence,
@@ -630,8 +632,9 @@ def dec_rows(items: Sequence) -> list[list[int]]:
     x8 = ops.modexp_rows(ops.pack_rows(bases, L8),
                          ops.pack_rows(exps, le8), m8, mu8)
     xs = _split_sizes(ops.unpack_rows(x8), sizes)
-    return [[(x - 1) // key.n * key.mu % key.n for x in xi]
-            for (key, _), xi in zip(items, xs)]
+    with span("host:dec_finish"):
+        return [[(x - 1) // key.n * key.mu % key.n for x in xi]
+                for (key, _), xi in zip(items, xs)]
 
 
 def add_rows(items: Sequence) -> list[list[int]]:

@@ -38,6 +38,7 @@ import jax
 import jax.numpy as jnp
 
 from ..core import bigint as bi
+from ..obs.trace import span
 from . import common as cm
 from . import montgomery as mg
 from . import ref as ref_impl
@@ -345,17 +346,20 @@ def _pow2_at_least(n: int, floor: int = 1) -> int:
 
 def pack_rows(xs, L8: int) -> np.ndarray:
     """List of ints -> (B, L8) little-endian radix-256 int32 limbs."""
-    out = np.zeros((len(xs), L8), np.int32)
-    for i, x in enumerate(xs):
-        b = int(x).to_bytes(L8, "little")    # OverflowError if too wide
-        out[i] = np.frombuffer(b, dtype=np.uint8)
-    return out
+    with span("host:pack_rows"):
+        out = np.zeros((len(xs), L8), np.int32)
+        for i, x in enumerate(xs):
+            b = int(x).to_bytes(L8, "little")    # OverflowError if too wide
+            out[i] = np.frombuffer(b, dtype=np.uint8)
+        return out
 
 
 def unpack_rows(arr) -> list[int]:
     """(B, L) radix-256 limb array -> list of Python ints."""
-    a = np.asarray(arr).astype(np.uint8)
-    return [int.from_bytes(row.tobytes(), "little") for row in a]
+    arr = bi.fetch(arr)
+    with span("host:unpack_rows"):
+        a = arr.astype(np.uint8)
+        return [int.from_bytes(row.tobytes(), "little") for row in a]
 
 
 @functools.lru_cache(maxsize=4096)
@@ -413,7 +417,7 @@ def mulmod_rows(a, b, m8, mu8) -> np.ndarray:
     (a, b, m8, mu8), bsz = _pad_rows([np.asarray(a), np.asarray(b),
                                       np.asarray(m8), np.asarray(mu8)],
                                      a.shape[0])
-    return np.asarray(_mulmod_rows8(a, b, m8, mu8))[:bsz]
+    return bi.fetch(_mulmod_rows8(a, b, m8, mu8))[:bsz]
 
 
 def modexp_rows(base, exp, m8, mu8, method: str | None = None) -> np.ndarray:
@@ -434,7 +438,7 @@ def modexp_rows(base, exp, m8, mu8, method: str | None = None) -> np.ndarray:
     (base, exp, m8, mu8), bsz = _pad_rows(
         [np.asarray(base), exp, np.asarray(m8), np.asarray(mu8)],
         base.shape[0])
-    return np.asarray(_MODEXP_ROWS8[method](base, exp, m8, mu8))[:bsz]
+    return bi.fetch(_MODEXP_ROWS8[method](base, exp, m8, mu8))[:bsz]
 
 
 @jax.jit
@@ -463,4 +467,4 @@ def prod_rows(x, m8, mu8) -> np.ndarray:
     """Row-wise modular product over axis 1: (R, N, L8) -> (R, L8)."""
     (x, m8, mu8), rsz = _pad_rows(
         [np.asarray(x), np.asarray(m8), np.asarray(mu8)], x.shape[0])
-    return np.asarray(_prod_rows8(x, m8, mu8))[:rsz]
+    return bi.fetch(_prod_rows8(x, m8, mu8))[:rsz]

@@ -1,7 +1,8 @@
 """repro.obs — unified tracing, metrics, and profiling for the stack.
 
-* :mod:`repro.obs.trace` — span tracer (virtual clock + kernel wall
-  clock) with a no-op default so the untraced path stays overhead-free;
+* :mod:`repro.obs.trace` — span tracer (virtual clock + host ms of each
+  launch call) with a no-op default so the untraced path stays
+  overhead-free, and ``span`` for spans on the profiler's clock;
 * :mod:`repro.obs.chrome_trace` — ``chrome://tracing`` / Perfetto export;
 * :mod:`repro.obs.metrics` — counters/gauges/histograms and the
   schema-versioned RunReport both protocol drivers emit;

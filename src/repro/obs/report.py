@@ -9,11 +9,12 @@ Usage::
 --trace``: spans + embedded RunReport) or a bare RunReport JSON.  The
 single-file view prints the phase table (crypto ops + virtual duration),
 the coalescing/dispatch breakdown, latency distributions, health alerts
-(``edge_sim --health``), and the top spans by measured kernel wall time;
-the two-file view diffs the core sections (ops, bytes, MSE) and compares
-the timing telemetry.  Diff mode exits 1 when the core sections differ
-(CI-gateable); ``--json`` switches either mode to machine-readable
-output.
+(``edge_sim --health``), and the top spans by ``wall_ms``: host
+milliseconds of the launch call (its dispatch, plus any conversion that
+waits for the device), not kernel time.  The two-file view diffs the
+core sections (ops, bytes, MSE) and compares the timing telemetry.
+Diff mode exits 1 when the core sections differ (CI-gateable);
+``--json`` switches either mode to machine-readable output.
 """
 from __future__ import annotations
 
@@ -127,7 +128,7 @@ def _top_spans(spans: list, n: int = 10) -> str:
                reverse=True)
     rows = []
     for s in timed[:n]:
-        cost = f"{s.wall_ms:.3f}ms wall" if key == "wall_ms" \
+        cost = f"{s.wall_ms:.3f}ms host" if key == "wall_ms" \
             else _fmt_s(s.dur) + " virtual"
         attrs = " ".join(f"{k}={v}" for k, v in sorted(s.attrs.items()))
         rows.append([s.name, s.cat, cost, attrs])

@@ -7,7 +7,7 @@ a churn event (leave / rejoin / fail injection / failure detection /
 recycled-update skip), or a health alert fired by a
 :class:`repro.obs.health.HealthMonitor` watcher.
 Spans carry the *virtual-clock* start/duration (the runtime's simulated
-seconds) plus, for real kernel launches, the measured host wall time —
+seconds) plus, for real kernel launches, the host wall time of the call —
 the two clocks are deliberately separate fields so determinism pins can
 compare span streams with the wall clock excluded.
 
@@ -23,10 +23,19 @@ Two tracer implementations share the interface:
 
 Instrumented call sites guard with ``if tracer.enabled:`` before building
 attr dicts, keeping the disabled path allocation-free.
+
+:func:`span` is the other view: a span on the *profiler's* clock, the one
+the device's executions are recorded on.  It wraps
+``jax.profiler.TraceAnnotation``, so it costs a few microseconds and
+records only while a ``jax.profiler`` session runs; the session holds the
+spans and writes them out with the device trace.  Where a boundary also
+has a virtual-clock span, both views give it the same name
+(``launch:<op>``, ``serve:launch:<op>``).
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Iterable
 
 #: the closed set of span categories; chrome_trace gives each its own lane
@@ -39,7 +48,9 @@ class Span:
     """One structured trace event.
 
     ``t``/``dur`` are virtual-clock seconds; ``wall_ms`` is measured host
-    milliseconds (kernel launches only, ``None`` elsewhere).  ``attrs``
+    milliseconds of a launch call: its dispatch, plus any conversion that
+    waits for the device (launches only, ``None`` elsewhere; not kernel
+    time, which :func:`span` and the profiler give).  ``attrs``
     hold the category-specific payload (op, shape, bytes, edge,
     coalesce width, backend, ...) as JSON-safe scalars.
     """
@@ -132,6 +143,21 @@ def as_tracer(trace) -> "Tracer | NullTracer":
     if isinstance(trace, (Tracer, NullTracer)):
         return trace
     return Tracer() if trace else NULL
+
+
+@functools.cache
+def _annotation():
+    from jax.profiler import TraceAnnotation   # only once a span is made
+    return TraceAnnotation
+
+
+def span(name: str, **attrs):
+    """Context manager: one span named ``name`` on the profiler's clock.
+
+    ``attrs`` (ints, bools or strings) arrive in the profiler trace as
+    the event's stats.  Spans go at call level, never inside a jitted
+    function or per element; docs/observability.md lists the names."""
+    return _annotation()(name, **attrs)
 
 
 def spans_from_dicts(dicts: Iterable[dict]) -> list[Span]:

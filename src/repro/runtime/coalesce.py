@@ -148,6 +148,9 @@ class CoalesceQueue:
         self.launch_widths: list[int] = []
         self.launch_walls: dict[str, dict[str, list[float]]] = {}
         self._warm_shapes: set[tuple] = set()
+        # the protocol round the master is in (set by the runner); labels
+        # the profiler-clock launch spans, None before the first round
+        self.round: int | None = None
 
     # -- submission ------------------------------------------------------
     def submit(self, op: str, args: tuple, cb: Callable) -> None:
@@ -237,10 +240,13 @@ class CoalesceQueue:
         # runner) — keep the telemetry honest
         fused = batchable and len(entries) > 1 and \
             (op != "matvec" or self._matvec_fuses(entries))
+        # a launch span closes before the callbacks, which run the
+        # driver's next steps
         if not fused:
             for e in entries:
                 t0 = time.perf_counter()
-                res = self._run_one(op, e.args)
+                with self._launch_span(op, 1, False):
+                    res = self._run_one(op, e.args)
                 self._observe_launch(op, shape, [e],
                                      (time.perf_counter() - t0) * 1e3,
                                      fused=False)
@@ -250,11 +256,17 @@ class CoalesceQueue:
         self.coalesced_ops += len(entries)
         self.launches += 1
         t0 = time.perf_counter()
-        results = self._run_group(op, entries)
+        with self._launch_span(op, len(entries), True):
+            results = self._run_group(op, entries)
         self._observe_launch(op, shape, entries,
                              (time.perf_counter() - t0) * 1e3, fused=True)
         for e, res in zip(entries, results):
             e.cb(res)
+
+    def _launch_span(self, op: str, width: int, fused: bool):
+        known = {} if self.round is None else {"round": self.round}
+        return trace_mod.span(f"launch:{op}", op=op, width=width,
+                              fused=fused, **known)
 
     def _observe_launch(self, op: str, shape: tuple, entries: list[_Entry],
                         wall_ms: float, fused: bool) -> None:
@@ -507,35 +519,37 @@ class CrossTenantCoalescer:
                    parts: list) -> None:
         total = sum(len(es) for _, es in parts)
         t0 = time.perf_counter()
-        if op == "enc":
-            items = []
-            for tq, entries in parts:
-                box = tq.box
-                flat = [int(v) for e in entries
-                        for v in np.asarray(e.args[0]).reshape(-1)]
-                # blinding draws: tenant's own rng, solo (entry) order
-                rs = [gold.rand_r(box.key, box.rng) for _ in flat]
-                items.append((box.key, flat, rs))
-            outs = pbatch.enc_rows(items)
-        elif op == "dec":
-            items = [(tq.box.key,
-                      _ints_of(_cat([e.args[0] for e in entries])))
-                     for tq, entries in parts]
-            outs = pbatch.dec_rows(items)
-        elif op == "add":
-            items = [(tq.box.key,
-                      _ints_of(_cat([e.args[0] for e in entries])),
-                      _ints_of(_cat([e.args[1] for e in entries])))
-                     for tq, entries in parts]
-            outs = pbatch.add_rows(items)
-        else:   # matvec
-            items = []
-            for tq, entries in parts:
-                Ks = np.stack([np.asarray(e.args[0], dtype=object)
-                               for e in entries])
-                cs = [_ints_of(e.args[1]) for e in entries]
-                items.append((tq.box.key, Ks, cs))
-            outs = pbatch.matvec_rows(items)
+        with trace_mod.span(f"serve:launch:{op}", width=total,
+                            tenants=len(parts)):
+            if op == "enc":
+                items = []
+                for tq, entries in parts:
+                    box = tq.box
+                    flat = [int(v) for e in entries
+                            for v in np.asarray(e.args[0]).reshape(-1)]
+                    # blinding draws: tenant's own rng, solo (entry) order
+                    rs = [gold.rand_r(box.key, box.rng) for _ in flat]
+                    items.append((box.key, flat, rs))
+                outs = pbatch.enc_rows(items)
+            elif op == "dec":
+                items = [(tq.box.key,
+                          _ints_of(_cat([e.args[0] for e in entries])))
+                         for tq, entries in parts]
+                outs = pbatch.dec_rows(items)
+            elif op == "add":
+                items = [(tq.box.key,
+                          _ints_of(_cat([e.args[0] for e in entries])),
+                          _ints_of(_cat([e.args[1] for e in entries])))
+                         for tq, entries in parts]
+                outs = pbatch.add_rows(items)
+            else:   # matvec
+                items = []
+                for tq, entries in parts:
+                    Ks = np.stack([np.asarray(e.args[0], dtype=object)
+                                   for e in entries])
+                    cs = [_ints_of(e.args[1]) for e in entries]
+                    items.append((tq.box.key, Ks, cs))
+                outs = pbatch.matvec_rows(items)
         wall_ms = (time.perf_counter() - t0) * 1e3
         for (tq, entries), out in zip(parts, outs):
             self._demux(tq, op, shape, entries, out, wall_ms, total)
@@ -561,62 +575,65 @@ class CrossTenantCoalescer:
                total: int) -> None:
         """Rebuild exactly the representation + telemetry the tenant's
         solo box call would have produced, then fire the callbacks."""
-        box = tq.box
-        if tq.counter is not None:
-            tq.counter.phase = entries[0].phase
-        if op == "enc":
-            sizes = [int(np.asarray(e.args[0]).size) for e in entries]
+        known = {} if tq.round is None else {"round": tq.round}
+        with trace_mod.span("serve:demux", op=op, tenant=str(tq.tenant),
+                            **known):
+            box = tq.box
             if tq.counter is not None:
-                tq.counter.bump("enc", len(out))
-            if box.batch and len(out) >= box.batch_min:
-                big = CipherTensor.from_ints(box.batch_key(), out)
-            else:
-                big = out
-            results = _split(big, sizes)
-        elif op == "dec":
-            sizes = [CoalesceQueue._size(e.args[0]) for e in entries]
-            if tq.counter is not None:
-                tq.counter.bump("dec", len(out))
-            results = _split(np.array(out, dtype=object), sizes)
-        elif op == "add":
-            sizes = [CoalesceQueue._size(e.args[0]) for e in entries]
-            if tq.counter is not None:
-                tq.counter.bump("mulmod", len(out))
-            all_ct = all(isinstance(e.args[0], CipherTensor)
-                         and isinstance(e.args[1], CipherTensor)
-                         for e in entries)
-            if box.batch and all_ct:
-                big = CipherTensor.from_ints(box.batch_key(), out)
-            else:
-                big = out
-            results = _split(big, sizes)
-        else:   # matvec — mirror _matvec_fuses + box.matvec rep rules
-            M, N = shape
-            E = len(entries)
-            if tq.counter is not None:
-                tq.counter.bump("modexp", E * M * N)
-                tq.counter.bump("mulmod", E * M * (N - 1))
-            results = []
-            if E * M * N >= box.batch_min:
-                ct_in = all(isinstance(e.args[1], CipherTensor)
-                            for e in entries)
-                for ints in out:
-                    results.append(
-                        CipherTensor.from_ints(box.batch_key(), ints)
-                        if ct_in else ints)
-            else:
-                for e, ints in zip(entries, out):
-                    if M * N >= box.batch_min \
-                            and isinstance(e.args[1], CipherTensor):
+                tq.counter.phase = entries[0].phase
+            if op == "enc":
+                sizes = [int(np.asarray(e.args[0]).size) for e in entries]
+                if tq.counter is not None:
+                    tq.counter.bump("enc", len(out))
+                if box.batch and len(out) >= box.batch_min:
+                    big = CipherTensor.from_ints(box.batch_key(), out)
+                else:
+                    big = out
+                results = _split(big, sizes)
+            elif op == "dec":
+                sizes = [CoalesceQueue._size(e.args[0]) for e in entries]
+                if tq.counter is not None:
+                    tq.counter.bump("dec", len(out))
+                results = _split(np.array(out, dtype=object), sizes)
+            elif op == "add":
+                sizes = [CoalesceQueue._size(e.args[0]) for e in entries]
+                if tq.counter is not None:
+                    tq.counter.bump("mulmod", len(out))
+                all_ct = all(isinstance(e.args[0], CipherTensor)
+                             and isinstance(e.args[1], CipherTensor)
+                             for e in entries)
+                if box.batch and all_ct:
+                    big = CipherTensor.from_ints(box.batch_key(), out)
+                else:
+                    big = out
+                results = _split(big, sizes)
+            else:   # matvec — mirror _matvec_fuses + box.matvec rep rules
+                M, N = shape
+                E = len(entries)
+                if tq.counter is not None:
+                    tq.counter.bump("modexp", E * M * N)
+                    tq.counter.bump("mulmod", E * M * (N - 1))
+                results = []
+                if E * M * N >= box.batch_min:
+                    ct_in = all(isinstance(e.args[1], CipherTensor)
+                                for e in entries)
+                    for ints in out:
                         results.append(
-                            CipherTensor.from_ints(box.batch_key(), ints))
-                    else:
-                        results.append(ints)
-        tq.launches += 1
-        if total > 1:
-            tq.coalesced_ops += len(entries)
-        tq._observe_launch(op, shape, entries, wall_ms,
-                           fused=total > 1 or len(entries) > 1)
+                            CipherTensor.from_ints(box.batch_key(), ints)
+                            if ct_in else ints)
+                else:
+                    for e, ints in zip(entries, out):
+                        if M * N >= box.batch_min \
+                                and isinstance(e.args[1], CipherTensor):
+                            results.append(
+                                CipherTensor.from_ints(box.batch_key(), ints))
+                        else:
+                            results.append(ints)
+            tq.launches += 1
+            if total > 1:
+                tq.coalesced_ops += len(entries)
+            tq._observe_launch(op, shape, entries, wall_ms,
+                               fused=total > 1 or len(entries) > 1)
         for e, res in zip(entries, results):
             e.cb(res)
 

@@ -198,6 +198,9 @@ class MasterActor:
         self.iter_times: list[float] = []
         self.t = -1
         self.done = False
+        # attributes of this deployment's profiler-clock driver spans
+        tenant = getattr(rt.cq, "tenant", None)
+        self._span_tag = {} if tenant is None else {"tenant": str(tenant)}
         # serving hooks: the engine chains admissions on completion and
         # may cut a tenant short after a given number of completed rounds
         self.on_done: "Callable | None" = None
@@ -337,9 +340,14 @@ class MasterActor:
                 rt.cq.submit("enc", (q_alpha,),
                              partial(self._reshare_ready, k, t))
 
+    def _driver_span(self, name: str):
+        return trace_mod.span(f"driver:{name}", round=self.t,
+                              **self._span_tag)
+
     def _iterate(self, t: int) -> None:
         rt, cfg = self.rt, self.rt.cfg
         self.t = t
+        rt.cq.round = t
         self.iter_start = rt.sched.now
         self.replies: dict[int, object] = {}
         self.w_cur: dict[int, float] = {}
@@ -373,35 +381,36 @@ class MasterActor:
                 if rt.tracer.enabled:
                     rt.tracer.add("reshare", "reshare", t=rt.sched.now,
                                   edge=k, round=t)
-        for k in range(cfg.K):
-            if k not in self.active:
-                continue                    # frozen handoff block
-            u1, u2 = self.wl.iter_inputs(self.wst, k)
-            self.w_cur[k] = float(np.sum(u1 + u2))
-            qz = np.asarray(gamma2(u1, cfg.spec))
-            qv = np.asarray(gamma2(u2, cfg.spec))
-            if rt.monitor.enabled:
-                cz, tz = gamma2_saturation(qz, cfg.spec)
-                cv2, tv2 = gamma2_saturation(qv, cfg.spec)
-                rt.monitor.observe_quant(t, cz + cv2, tz + tv2)
-            if cfg.recycle and self.last_q[k] is not None \
-                    and int(np.max(np.abs(qz - self.last_q[k][0]))) \
-                    <= cfg.recycle_tol \
-                    and int(np.max(np.abs(qv - self.last_q[k][1]))) \
-                    <= cfg.recycle_tol:
-                # recycled update: skip enc + step + dec; _finalize
-                # re-dequantizes the cached integer chain with THIS
-                # round's w-sum (see run_protocol for why tol=0 is exact)
-                rt.counter.bump("recycled", rt.nk)
-                self.recycled += 1
-                self.recycled_now.add(k)
-                if rt.tracer.enabled:
-                    rt.tracer.add("churn:recycle", "churn", t=rt.sched.now,
-                                  edge=k, round=t)
-                continue
-            self._q_rounds.setdefault(t, {})[k] = (qz, qv)
-            rt.cq.submit("enc", (qz,), partial(self._enc_done, t, k, "z"))
-            rt.cq.submit("enc", (qv,), partial(self._enc_done, t, k, "v"))
+        with self._driver_span("quantize"):
+            for k in range(cfg.K):
+                if k not in self.active:
+                    continue                    # frozen handoff block
+                u1, u2 = self.wl.iter_inputs(self.wst, k)
+                self.w_cur[k] = float(np.sum(u1 + u2))
+                qz = np.asarray(gamma2(u1, cfg.spec))
+                qv = np.asarray(gamma2(u2, cfg.spec))
+                if rt.monitor.enabled:
+                    cz, tz = gamma2_saturation(qz, cfg.spec)
+                    cv2, tv2 = gamma2_saturation(qv, cfg.spec)
+                    rt.monitor.observe_quant(t, cz + cv2, tz + tv2)
+                if cfg.recycle and self.last_q[k] is not None \
+                        and int(np.max(np.abs(qz - self.last_q[k][0]))) \
+                        <= cfg.recycle_tol \
+                        and int(np.max(np.abs(qv - self.last_q[k][1]))) \
+                        <= cfg.recycle_tol:
+                    # recycled update: skip enc + step + dec; _finalize
+                    # re-dequantizes the cached integer chain with THIS
+                    # round's w-sum (see run_protocol for why tol=0 is exact)
+                    rt.counter.bump("recycled", rt.nk)
+                    self.recycled += 1
+                    self.recycled_now.add(k)
+                    if rt.tracer.enabled:
+                        rt.tracer.add("churn:recycle", "churn",
+                                      t=rt.sched.now, edge=k, round=t)
+                    continue
+                self._q_rounds.setdefault(t, {})[k] = (qz, qv)
+                rt.cq.submit("enc", (qz,), partial(self._enc_done, t, k, "z"))
+                rt.cq.submit("enc", (qv,), partial(self._enc_done, t, k, "v"))
         # the reply barrier for this round: live edges we actually asked
         # (a failed edge stays in here — the master doesn't know yet)
         self._round_edges = self.active - self.recycled_now
@@ -540,9 +549,10 @@ class MasterActor:
     def _dec_done(self, k: int, w_sum: float, fresh: bool, R) -> None:
         rt, cfg = self.rt, self.rt.cfg
         sl = slice(k * rt.nk, (k + 1) * rt.nk)
-        R = np.asarray(R).astype(np.float64)
-        self._x_new[sl] = np.asarray(dequantize_theorem1(
-            R, self.C_rowsums[k], w_sum, rt.nk, cfg.spec))
+        with self._driver_span("dequantize"):
+            R = np.asarray(R).astype(np.float64)
+            self._x_new[sl] = np.asarray(dequantize_theorem1(
+                R, self.C_rowsums[k], w_sum, rt.nk, cfg.spec))
         if fresh and cfg.recycle:
             # the recycle cache pairs the decrypted chain with the exact
             # quantized inputs that produced it — only a CURRENT-round
@@ -569,7 +579,8 @@ class MasterActor:
             rt.monitor.observe_round(self.t, float(np.mean(
                 (self._x_new - self.wst.x_prev) ** 2)))
         # master updates (10b)/(10c) with the (t-1) iterate — Jacobi order
-        self.wl.global_update(self.wst, self._x_new)
+        with self._driver_span("global_update"):
+            self.wl.global_update(self.wst, self._x_new)
         self.history[self.t] = self._x_new
         self.iter_times.append(rt.sched.now)
         if rt.tracer.enabled:
