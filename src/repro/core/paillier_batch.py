@@ -136,16 +136,19 @@ def modexp_crt_limbs(bk: BatchKey, bases: Sequence[int], exps,
                      fixed: bool = False) -> jnp.ndarray:
     """[b^e mod n^2] as (B, L16(n^2)) limbs; ``exps`` scalar or per-element.
 
-    The two half-space ModExp launches size their exponent limbs to the
-    batch maximum AFTER the phi reduction, so small exponents (quantized
-    Gamma_2 values, ~20 bits) pay for ~2 limbs, not the full key width.
+    Per-element exponents run two half-space ModExp ladders, one after the
+    other, with their exponent limbs sized to the batch maximum AFTER the
+    phi reduction, so small exponents (quantized Gamma_2 values, ~20 bits)
+    pay for ~2 limbs, not the full key width.
 
     ``fixed=True`` opts a SCALAR exponent into the host-known fixed-window
-    ladder (``ops.modexp_fixed``): the 4-bit schedule is baked into the
-    trace, dropping the per-window oblivious table select.  Only pass it
-    for KEY-CONSTANT exponents (enc's ``n``, dec's ``lam``) — every
-    distinct exponent value compiles its own executable.  Per-element
-    exponent lists ignore the flag.
+    ladder (``ops.modexp_fixed_pair``): the 4-bit schedules are baked into
+    the trace, dropping the per-window oblivious table select, and on the
+    Montgomery ``ref`` path both halves run stacked as ONE ladder over 2B
+    rows (each launch is counted in ``ops.FIXED_CRT``).  Only pass it for
+    KEY-CONSTANT exponents (enc's ``n``, dec's ``lam``) — every distinct
+    exponent value compiles its own executable.  Per-element exponent
+    lists ignore the flag.
     """
     key, vk = bk.key, bk.vk
     B = len(bases)
@@ -165,8 +168,9 @@ def modexp_crt_limbs(bk: BatchKey, bases: Sequence[int], exps,
         ep_s, eq_s = abs(scalar_e) % key.phi_p2, abs(scalar_e) % key.phi_q2
 
         def fixed_body(bp, bq):
-            xp = ops.modexp_fixed(bp, ep_s, vk.pack_p2, backend=backend)
-            xq = ops.modexp_fixed(bq, eq_s, vk.pack_q2, backend=backend)
+            xp, xq = ops.modexp_fixed_pair(bp, ep_s, vk.pack_p2,
+                                           bq, eq_s, vk.pack_q2,
+                                           backend=backend)
             return pv.crt_combine_batch(vk, xp, xq, backend=backend)
 
         # the reduce impl resolves when the body TRACES, so it is part of
@@ -174,6 +178,7 @@ def modexp_crt_limbs(bk: BatchKey, bases: Sequence[int], exps,
         # would silently replay the other impl's executable
         fn = pv._cached_jit(vk, ("crt_modexp_fixed", backend, ep_s, eq_s,
                                  ops.active_reduce_impl()), fixed_body)
+        ops.count_fixed_crt(vk.pack_p2, vk.pack_q2, backend)
         return fn(*_shard_batch(bp, bq))
 
     le = max(1, max(bi.n_limbs_for(e) for e in ep + eq))
@@ -218,13 +223,15 @@ def modexp_crt_limbs_in(bk: BatchKey, base_limbs: jnp.ndarray, exps,
         def fixed_body(c):
             cp = pv._reduce_into(c, vk.pack_p2, backend)
             cq = pv._reduce_into(c, vk.pack_q2, backend)
-            xp = ops.modexp_fixed(cp, ep_s, vk.pack_p2, backend=backend)
-            xq = ops.modexp_fixed(cq, eq_s, vk.pack_q2, backend=backend)
+            xp, xq = ops.modexp_fixed_pair(cp, ep_s, vk.pack_p2,
+                                           cq, eq_s, vk.pack_q2,
+                                           backend=backend)
             return pv.crt_combine_batch(vk, xp, xq, backend=backend)
 
         fn = pv._cached_jit(
             vk, ("crt_modexp_limbs_fixed", backend, ep_s, eq_s,
                  ops.active_reduce_impl()), fixed_body)
+        ops.count_fixed_crt(vk.pack_p2, vk.pack_q2, backend)
         return fn(_shard_batch(base_limbs))
 
     ep = [e % key.phi_p2 for e in exps]

@@ -182,10 +182,13 @@ def decrypt_batch_limbs(vk: VecKey, c_limbs: jax.Array,
     multiplicatively via n^{-1} mod 2^k (no big-int division circuit).
     The result is the complete residue mod n — no 63-bit truncation.
     """
-    # the reduce impl resolves at trace time inside ops.modexp_fixed, so
-    # it must be part of the cache identity (env flips retrace, not replay)
-    return _cached_jit(vk, ("dec", backend, ops.active_reduce_impl()),
-                       lambda c: _decrypt_impl(vk, c, backend))(c_limbs)
+    # the reduce impl resolves at trace time inside ops.modexp_fixed_pair,
+    # so it must be part of the cache identity (env flips retrace, not
+    # replay)
+    fn = _cached_jit(vk, ("dec", backend, ops.active_reduce_impl()),
+                     lambda c: _decrypt_impl(vk, c, backend))
+    ops.count_fixed_crt(vk.pack_p2, vk.pack_q2, backend)
+    return fn(c_limbs)
 
 
 def _decrypt_impl(vk: VecKey, c_limbs: jax.Array,
@@ -198,8 +201,8 @@ def _decrypt_impl(vk: VecKey, c_limbs: jax.Array,
     # applies (static schedule, no oblivious table selects)
     lam_p = bi.to_ints(np.asarray(vk.lam_p).reshape(1, -1))[0]
     lam_q = bi.to_ints(np.asarray(vk.lam_q).reshape(1, -1))[0]
-    xp = ops.modexp_fixed(cp, lam_p, vk.pack_p2, backend=backend)
-    xq = ops.modexp_fixed(cq, lam_q, vk.pack_q2, backend=backend)
+    xp, xq = ops.modexp_fixed_pair(cp, lam_p, vk.pack_p2,
+                                   cq, lam_q, vk.pack_q2, backend=backend)
     x = crt_combine_batch(vk, xp, xq, backend=backend)    # c^lam mod n^2
     # alpha = (x - 1) / n  — exact division, multiplicative
     Ln = vk.pack_n.L16

@@ -26,15 +26,18 @@ within ``carry2d``'s fold-variant contract (DESIGN.md §2 headroom note).
 The exponent ladders mirror ``common.modexp2d``/``modexp2d_win4`` with the
 Barrett mulmod swapped for ``montmul2d`` (the ``REPRO_REDUCE_IMPL`` knob in
 ``kernels/ops.py`` selects between them; Barrett stays the oracle).  The
-``*_fixed`` ladders take a host-known exponent shared by the whole batch
-(enc's ``r^n``, dec's ``c^lam``) as a static MSB-first 4-bit window tuple:
+``*_fixed`` ladders take a host-known exponent shared by each row group
+(enc's ``r^n``, dec's ``c^lam``) as a static MSB-first 4-bit window schedule:
 the table select becomes a constant-index gather (the access pattern is
 baked into the trace, so runtime behaviour stays input-independent) and the
 ladder length tracks the exponent's true bit-length instead of the padded
-limb width.
+limb width.  The Montgomery one also takes per-row moduli and a schedule per
+row group, so the two CRT halves of a fixed exponentiation run stacked as
+one ladder (``ops.modexp_fixed_pair``) instead of two in a row.
 """
 from __future__ import annotations
 
+import numpy as np
 import jax
 import jax.numpy as jnp
 
@@ -62,8 +65,11 @@ def _bcast_m(m: jax.Array, bsz: int) -> jax.Array:
     return m
 
 
-def redc2d(t: jax.Array, m: jax.Array, mp: int) -> jax.Array:
+def redc2d(t: jax.Array, m: jax.Array, mp) -> jax.Array:
     """t (B, <=2L) * R^{-1} mod m -> (B, L); requires t < R*m, m odd.
+
+    ``m`` is (1, L) or per-row (B, L); ``mp`` (``-m^{-1} mod 256``) is an
+    int or a per-row (B,) array to match.
 
     One sequential sweep of L steps: step i zeroes limb i by adding
     ``u = (t[i] * mp) & 0xFF`` copies of m at position i, carrying through
@@ -196,7 +202,9 @@ def exp_windows(e: int) -> tuple[int, ...]:
 
     Length tracks ``e.bit_length()`` rounded up to a nibble, so small
     key-constant exponents get proportionally shorter ladders.  ``e = 0``
-    yields the empty tuple (the ladders then return 1).
+    yields the empty tuple (the ladders then return 1).  A stacked ladder
+    runs several schedules at once: :func:`exp_window_rows` front-pads them
+    to one length.
     """
     if e < 0:
         raise ValueError("exp_windows requires a non-negative exponent")
@@ -204,37 +212,60 @@ def exp_windows(e: int) -> tuple[int, ...]:
     return tuple((e >> (4 * j)) & 0xF for j in reversed(range(n_win)))
 
 
-def _win_at(win_arr: jax.Array, w: jax.Array) -> jax.Array:
-    """Window value at position w; win_arr is a (1, n_win) int32 row."""
-    return jax.lax.dynamic_slice(win_arr, (w * 0, w), (1, 1))[0, 0]
+def exp_window_rows(*es: int) -> np.ndarray:
+    """Host-known exponents -> (G, n_win) int32 schedule, one row each.
+
+    Shorter schedules are padded at the FRONT with zero windows: a leading
+    zero window squares mont(1) and multiplies it by ``table[0] = mont(1)``,
+    so the result is unchanged and the ladder runs as long as the longest
+    exponent.
+    """
+    wins = [exp_windows(e) for e in es]
+    n_win = max(map(len, wins), default=0)
+    return np.asarray([(0,) * (n_win - len(w)) + w for w in wins],
+                      np.int32).reshape(len(wins), n_win)
 
 
-def modexp2d_mont_fixed(base, win_arr, m, mp, r1, r2):
-    """Fixed (batch-shared, host-known) exponent ladder, Montgomery domain.
+def _win_at(win_arr: jax.Array, w: jax.Array, g: int = 0) -> jax.Array:
+    """Window value at position w of schedule row g (a static index)."""
+    return jax.lax.dynamic_slice(win_arr, (w * 0 + g, w), (1, 1))[0, 0]
 
-    ``win_arr`` is the (1, n_win) int32 row of MSB-first 4-bit windows from
-    :func:`exp_windows` (passed as an operand so Pallas kernels don't
-    capture trace constants); the 16-entry power table is selected with a
-    plain gather instead of the oblivious masked sum (the schedule is
-    input-independent — it only depends on the key-constant exponent), and
-    leading zero windows are already trimmed — the two wins of knowing the
-    exponent host-side.
+
+def modexp2d_mont_fixed(base, win_arr, m, mp, r1, r2, groups=()):
+    """Fixed (host-known, per row group) exponent ladder, Montgomery domain.
+
+    ``win_arr`` is the (G, n_win) int32 schedule of MSB-first 4-bit windows
+    from :func:`exp_window_rows` (passed as an operand so Pallas kernels
+    don't capture trace constants); ``groups`` is the static tuple of the G
+    groups' row counts, in row order (``()``: one group, the whole batch).
+    ``m``, ``r1`` and ``r2`` are (1, L) or per-row (bsz, L), and ``mp`` an
+    int or a per-row (bsz,) array, so each group may have its own modulus.
+    The 16-entry power table is selected with a plain gather per group,
+    joined by a static row mask, instead of the oblivious masked sum (the
+    schedule is input-independent — it only depends on the key-constant
+    exponents), and leading zero windows of the longest exponent are
+    already trimmed — the two wins of knowing the exponent host-side.
     """
     bsz, L = base.shape[0], m.shape[1]
-    n_win = win_arr.shape[1]
+    n_grp, n_win = win_arr.shape
     m = _bcast_m(m, bsz)
     one = _mont_one(r1, bsz)
     if n_win == 0:
         return from_mont2d(one, m, mp)
     base_m = to_mont2d(base, m, mp, r2)
     table = _mont_table16(base_m, one, m, mp)
+    row_grp = np.repeat(np.arange(n_grp), groups or (bsz,))[:, None]
 
     def body(w, res):
         for _ in range(4):
             res = montmul2d(res, res, m, mp)
-        win = _win_at(win_arr, w)
-        sel = jax.lax.dynamic_slice(table, (win, win * 0, win * 0),
-                                    (1, bsz, L))[0]
+        sel = None
+        for g in range(n_grp):
+            win = _win_at(win_arr, w, g)
+            sel_g = jax.lax.dynamic_slice(table, (win, win * 0, win * 0),
+                                          (1, bsz, L))[0]
+            sel = sel_g if sel is None else jnp.where(row_grp == g, sel_g,
+                                                      sel)
         return montmul2d(res, sel, m, mp)
 
     res = jax.lax.fori_loop(0, n_win, body, one)
@@ -243,7 +274,7 @@ def modexp2d_mont_fixed(base, win_arr, m, mp, r1, r2):
 
 def modexp2d_fixed_barrett(base, win_arr, m, mu):
     """Fixed-exponent ladder on the Barrett oracle (REPRO_REDUCE_IMPL
-    fallback and the even-modulus path); same (1, n_win) window schedule."""
+    fallback and the even-modulus path); one group's (1, n_win) schedule."""
     bsz, L = base.shape[0], m.shape[1]
     n_win = win_arr.shape[1]
     one = jnp.zeros((bsz, L), jnp.int32).at[:, 0].set(1)

@@ -73,6 +73,7 @@ from ..core import protocol
 from ..core.quantization import (gamma1, gamma2, gamma1_saturation,
                                  gamma2_saturation, dequantize_theorem1)
 from ..kernels import compile_cache
+from ..kernels import ops as kernel_ops
 from ..obs import health as health_mod
 from ..obs import ledger as ledger_mod
 from ..obs import metrics as obs_metrics
@@ -624,6 +625,9 @@ class _Runtime:
         self.monitor = monitor
         self.edge_actors: list = []   # filled by run_on_runtime (the
                                       # fault-injection handle for fails)
+        # process-wide fixed CRT ladder launches before this run (the
+        # report carries the difference)
+        self.fixed_crt_start = dict(kernel_ops.FIXED_CRT)
 
 
 def auto_hold_ticks(topo: Topology, transport: Transport, tick_s: float,
@@ -866,6 +870,10 @@ def collect_result(rt, master, wl, mode, *, driver: str = "runtime",
         # "profile" (process-level events since the previous report) is
         # filled by build_run_report, which drains the global log
         "compile_cache": compile_cache.stats(),
+        # fixed CRT ladder launches (enc r^n, dec c^lam) since the runtime
+        # was built: "stacked" ran both halves as one ladder, "split" as two
+        "fixed_crt": {k: n - rt.fixed_crt_start[k]
+                      for k, n in kernel_ops.FIXED_CRT.items()},
     }
     if key_bits is not None:
         # achieved-vs-peak limb-ops on the virtual clock: utilization of

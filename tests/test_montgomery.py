@@ -180,6 +180,90 @@ def test_exp_windows_schedule():
     assert mg.exp_windows(1) == (1,)
     assert mg.exp_windows(0xAB3) == (0xA, 0xB, 0x3)
     assert mg.exp_windows(0x1F) == (0x1, 0xF)   # trimmed to true length
+    # stacked schedules: the shorter one is front-padded with zero windows
+    assert mg.exp_window_rows(0xAB3, 0x1F).tolist() == [[0xA, 0xB, 0x3],
+                                                         [0x0, 0x1, 0xF]]
+    assert mg.exp_window_rows(0, 0x5).tolist() == [[0x0], [0x5]]
+    assert mg.exp_window_rows(0, 0).shape == (2, 0)
+    assert mg.exp_window_rows(0x1F).tolist() == [[0x1, 0xF]]
+
+
+# ---------------------------------------------------------------------------
+# stacked CRT halves: one fixed ladder over both moduli
+# ---------------------------------------------------------------------------
+
+def _odd(bits: int, rng: random.Random) -> int:
+    return rng.getrandbits(bits) | (1 << (bits - 1)) | 1
+
+
+def _exp(bits: int, rng: random.Random) -> int:
+    return rng.getrandbits(bits) | (1 << (bits - 1)) if bits else 0
+
+
+@pytest.mark.parametrize("p_bits,q_bits,ep_bits,eq_bits,B", [
+    (256, 256, 250, 256, 1),      # window counts 63 and 64
+    (256, 256, 250, 256, 3),
+    (256, 256, 250, 256, 128),
+    (256, 256, 60, 256, 3),       # 15 windows against 64
+    (256, 256, 256, 0, 3),        # q's exponent 0: empty schedule
+    (256, 256, 0, 0, 3),          # both empty: every row is 1
+    (264, 256, 200, 120, 3),      # p wider: L8 33 and 32
+    (200, 256, 120, 200, 3),      # q wider: L8 25 and 32
+])
+def test_modexp_fixed_pair_stacked_vs_gold(p_bits, q_bits, ep_bits, eq_bits,
+                                           B):
+    """Both groups of the stacked ladder bit-exact against pow(), next to
+    the two-call Barrett arm, which must agree with them."""
+    rng = random.Random(p_bits * 7 + q_bits + ep_bits + eq_bits + B)
+    pp = ops.pack_modulus(_odd(p_bits, rng))
+    pq = ops.pack_modulus(_odd(q_bits, rng))
+    ep, eq = _exp(ep_bits, rng), _exp(eq_bits, rng)
+    xs = [rng.randrange(pp.m_int) for _ in range(B)]
+    ys = [rng.randrange(pq.m_int) for _ in range(B)]
+    want_p = [pow(x, ep, pp.m_int) for x in xs]
+    want_q = [pow(y, eq, pq.m_int) for y in ys]
+    assert ops.fixed_pair_stacks(pp, pq, "ref", "montgomery")
+    assert not ops.fixed_pair_stacks(pp, pq, "ref", "barrett")
+    for impl in ("montgomery", "barrett"):
+        xp, xq = ops.modexp_fixed_pair(_limbs(xs, pp.L16), ep, pp,
+                                       _limbs(ys, pq.L16), eq, pq,
+                                       backend="ref", reduce_impl=impl)
+        assert xp.shape == (B, pp.L16) and xq.shape == (B, pq.L16)
+        assert bi.to_ints(xp) == want_p, impl
+        assert bi.to_ints(xq) == want_q, impl
+
+
+def test_stacked_kernel_groups_match_single_group_ladders():
+    """The G = 2 kernel's rows equal the G = 1 kernel (and the Barrett
+    ladder) run on each group alone: stacking changes the layout, not
+    the arithmetic."""
+    rng = random.Random(5)
+    packs = [ops.pack_modulus(_odd(256, rng)) for _ in range(2)]
+    es = [_exp(256, rng), _exp(180, rng)]
+    B = 4
+    b8 = [jnp.asarray(np.stack([ops._to8(rng.randrange(p.m_int), p.L8)
+                                for _ in range(B)])) for p in packs]
+    singles = []
+    for p, e, b in zip(packs, es, b8):
+        win = jnp.asarray(mg.exp_window_rows(e))
+        mont = mg.modexp2d_mont_fixed(b, win, jnp.asarray(p.m8), p.mp8,
+                                      jnp.asarray(p.r1_8),
+                                      jnp.asarray(p.r2_8))
+        barr = mg.modexp2d_fixed_barrett(b, win, jnp.asarray(p.m8),
+                                         jnp.asarray(p.mu8))
+        assert np.array_equal(mont, barr)
+        singles.append(np.asarray(mont))
+
+    def rows(attr):
+        return jnp.asarray(np.concatenate(
+            [np.repeat(np.asarray(getattr(p, attr)).reshape(1, -1), B, 0)
+             for p in packs]))
+
+    mp = jnp.asarray(np.repeat([p.mp8 for p in packs], B).astype(np.int32))
+    stacked = mg.modexp2d_mont_fixed(
+        jnp.concatenate(b8), jnp.asarray(mg.exp_window_rows(*es)),
+        rows("m8"), mp, rows("r1_8"), rows("r2_8"), groups=(B, B))
+    assert np.array_equal(np.asarray(stacked), np.concatenate(singles))
 
 
 # ---------------------------------------------------------------------------

@@ -11,11 +11,13 @@ import random
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core import paillier as gold
 from repro.core import paillier_batch as pb
+from repro.core import paillier_vec as pv
 from repro.kernels import ops
 from repro.kernels.modexp import modexp_pallas
 
@@ -81,6 +83,30 @@ def test_modexp_fixed_crt_half(key, vk, one_chip):
     e = key.n % key.phi_p2
     _compile(lambda b: ops.modexp_fixed(b, e, pack),
              _limbs(BATCH, pack.L16, one_chip))
+
+
+@pytest.fixture(scope="module")
+def four_chips(topo):
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+    mesh = Mesh(np.asarray(topo.devices).reshape(-1), ("batch",))
+    return NamedSharding(mesh, PartitionSpec("batch"))
+
+
+@pytest.mark.parametrize("chips", ["one_chip", "four_chips"])
+def test_modexp_fixed_pair_stacked(key, vk, chips, request):
+    """enc's r^n with both CRT halves stacked in one ladder, at the solo
+    cell's 180 values (2 x 180 rows at L8 = 256), then recombined; on one
+    chip, and batch-sharded over four as ``_shard_batch`` lays it out."""
+    sharding = request.getfixturevalue(chips)
+    e_p, e_q = key.n % key.phi_p2, key.n % key.phi_q2
+
+    def enc_rn(bp, bq):
+        xp, xq = ops.modexp_fixed_pair(bp, e_p, vk.pack_p2,
+                                       bq, e_q, vk.pack_q2)
+        return pv.crt_combine_batch(vk, xp, xq)
+
+    _compile(enc_rn, _limbs(180, vk.pack_p2.L16, sharding),
+             _limbs(180, vk.pack_q2.L16, sharding))
 
 
 def test_modexp_per_element_crt_half(vk, one_chip):
