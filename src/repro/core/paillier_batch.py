@@ -96,7 +96,14 @@ def rand_r_vec(key: gold.PaillierKey, count: int,
 # Core primitive: batched base^e mod n^2 via the CRT half spaces
 # ---------------------------------------------------------------------------
 
-def _shard_batch(*arrays):
+# Ladder rows launched in this process, cumulative: ``rows`` (values,
+# or the matvec's exponent rows) and ``one_chip`` (those of a launch that
+# ran on one chip).  The runtime's coalescing queue keeps their
+# differences per protocol round.
+LAYOUT = {"rows": 0, "one_chip": 0}
+
+
+def _shard_batch(*arrays, count: bool = True):
     """Lay ``(B, ...)`` operand arrays across the local ``batch`` device mesh.
 
     Single-device hosts (the common container) get the arrays back
@@ -106,9 +113,17 @@ def _shard_batch(*arrays):
     K>=64 topologies use every chip with zero cross-device traffic until
     the caller gathers.  Batches not divisible by the device count stay
     unsharded (the jit still runs, just unpartitioned).
+
+    With ``count`` the first array's rows are one ladder launch's rows in
+    :data:`LAYOUT`, on one chip unless that array was spread.
     """
     from ..launch import mesh as mesh_mod
     m = mesh_mod.kernel_mesh()
+    if count:
+        rows = int(np.shape(arrays[0])[0])
+        LAYOUT["rows"] += rows
+        if m is None or rows % int(m.devices.size):
+            LAYOUT["one_chip"] += rows
     if m is not None:
         from jax.sharding import NamedSharding, PartitionSpec
         ndev = int(m.devices.size)
@@ -441,7 +456,8 @@ def matvec_many(bk: BatchKey, Ks, cs_list: Sequence,
 
         if ct_in:
             c_limbs = _shard_batch(
-                jnp.concatenate([c.limbs for c in cs_list], axis=0))
+                jnp.concatenate([c.limbs for c in cs_list], axis=0),
+                count=False)
 
             def powed_ct_body(c, ep, eq):
                 cp = pv._reduce_into(c, vk.pack_p2, backend)
@@ -464,7 +480,7 @@ def matvec_many(bk: BatchKey, Ks, cs_list: Sequence,
                 xq = ops.modexp(bcast(bq), eq, vk.pack_q2, backend=backend)
                 return pv.crt_combine_batch(vk, xp, xq, backend=backend)
 
-            bp, bq = _shard_batch(bp, bq)
+            bp, bq = _shard_batch(bp, bq, count=False)
             powed = pv._cached_jit(
                 vk, ("crt_mv", backend, M, N, ops.active_reduce_impl()),
                 powed_body)(bp, ep_l, bq, eq_l)

@@ -46,6 +46,7 @@ import time
 from typing import Callable
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 
 from ..core import cipher_tensor as ct_mod
@@ -151,6 +152,9 @@ class CoalesceQueue:
         # the protocol round the master is in (set by the runner); labels
         # the profiler-clock launch spans, None before the first round
         self.round: int | None = None
+        # per protocol round, how its launches laid their ladder rows over
+        # the chips: the differences of ``paillier_batch.LAYOUT``
+        self.layout: dict[int, dict[str, int]] = {}
 
     # -- submission ------------------------------------------------------
     def submit(self, op: str, args: tuple, cb: Callable) -> None:
@@ -245,8 +249,10 @@ class CoalesceQueue:
         if not fused:
             for e in entries:
                 t0 = time.perf_counter()
-                with self._launch_span(op, 1, False):
+                with self._launch_span(op, 1, False) as sp:
+                    before = dict(pbatch.LAYOUT)
                     res = self._run_one(op, e.args)
+                    self._record_layout(before, sp)
                 self._observe_launch(op, shape, [e],
                                      (time.perf_counter() - t0) * 1e3,
                                      fused=False)
@@ -256,8 +262,10 @@ class CoalesceQueue:
         self.coalesced_ops += len(entries)
         self.launches += 1
         t0 = time.perf_counter()
-        with self._launch_span(op, len(entries), True):
+        with self._launch_span(op, len(entries), True) as sp:
+            before = dict(pbatch.LAYOUT)
             results = self._run_group(op, entries)
+            self._record_layout(before, sp)
         self._observe_launch(op, shape, entries,
                              (time.perf_counter() - t0) * 1e3, fused=True)
         for e, res in zip(entries, results):
@@ -267,6 +275,21 @@ class CoalesceQueue:
         known = {} if self.round is None else {"round": self.round}
         return trace_mod.span(f"launch:{op}", op=op, width=width,
                               fused=fused, **known)
+
+    def _record_layout(self, before: dict, sp) -> None:
+        """Adds a launch's ladder rows, as ``paillier_batch.LAYOUT`` moved
+        during it, to its round's count, and to its span the chips its
+        batch was spread over; a launch that ran no ladder (``add``)
+        records nothing."""
+        moved = {k: n - before[k] for k, n in pbatch.LAYOUT.items()}
+        if not moved["rows"]:
+            return
+        sp.set_metadata(chips=1 if moved["one_chip"] == moved["rows"]
+                        else jax.local_device_count())
+        if self.round is not None:
+            tally = self.layout.setdefault(self.round, dict.fromkeys(moved, 0))
+            for k, n in moved.items():
+                tally[k] += n
 
     def _observe_launch(self, op: str, shape: tuple, entries: list[_Entry],
                         wall_ms: float, fused: bool) -> None:

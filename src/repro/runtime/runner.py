@@ -874,6 +874,9 @@ def collect_result(rt, master, wl, mode, *, driver: str = "runtime",
         # was built: "stacked" ran both halves as one ladder, "split" as two
         "fixed_crt": {k: n - rt.fixed_crt_start[k]
                       for k, n in kernel_ops.FIXED_CRT.items()},
+        # per protocol round, its launches' ladder rows and those that ran
+        # on one chip (``paillier_batch.LAYOUT``)
+        "layout": {t: dict(n) for t, n in sorted(cq.layout.items())},
     }
     if key_bits is not None:
         # achieved-vs-peak limb-ops on the virtual clock: utilization of
