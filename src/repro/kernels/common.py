@@ -39,106 +39,74 @@ def limbs8_to16(x: jax.Array) -> jax.Array:
 
 
 # ---------------------------------------------------------------------------
-# Pallas-compatible (fori_loop + dynamic_slice only) radix-256 primitives.
-# These run both inside pallas_call bodies and as plain jnp (the oracle).
-# All operate on 2-D blocks (B, L).
+# Radix-256 primitives on 2-D blocks (B, L).  These run both inside
+# pallas_call bodies (interpret mode) and as plain jnp (the oracle).
 # ---------------------------------------------------------------------------
 
-# Carry strategy (§Perf iteration log):
-#   "seq"  — exact 2L-step sequential scan (one limb per step);
-#   "fold" — 4 vectorized radix-folding rounds bound every coefficient to
-#            [0, 256], then the residual one-bit cascade is resolved with a
-#            log-depth (generate, propagate) associative scan — the classic
-#            carry-lookahead adder, vectorized over the batch.
-#            MEASURED on XLA CPU: 1.9x SLOWER than "seq" (associative_scan
-#            lowers to log-depth concat materializations; single-core loop
-#            is cache-friendly). Hypothesis refuted for CPU; selectable for
-#            real-TPU evaluation (EXPERIMENTS.md §Perf).
-CARRY_IMPL = _os.environ.get("REPRO_CARRY_IMPL", "seq")
+# Carries, borrows and comparisons resolve in constant depth: no per-limb
+# loop.  A few vectorised radix folds bound every coefficient to [0, 256];
+# what is left is a one-bit cascade, which a carry-lookahead over the limb
+# axis settles in one running maximum (``_lookahead``).  A borrow is the
+# same cascade on the digits of ``a - b``, and the borrow out of the top
+# limb is the comparison ``a < b``, so a conditional subtract needs no
+# separate compare.
+
+# Four folds take any coefficient in [0, 2^31) to [0, 256]: the excess over
+# a limb shrinks to < 2^23, < 2^15, < 2^7 and then <= 1.
+_N_FOLDS = 4
 
 
-def _carry2d_seq(acc: jax.Array) -> jax.Array:
-    bsz, nl = acc.shape
-
-    def step(i, st):
-        c, out = st
-        t = jax.lax.dynamic_slice(acc, (0, i), (bsz, 1))[:, 0] + c
-        out = jax.lax.dynamic_update_slice(out, (t & RADIX_MASK)[:, None],
-                                           (0, i))
-        return t >> RADIX_BITS, out
-
-    _, out = jax.lax.fori_loop(
-        0, nl, step, (jnp.zeros((bsz,), jnp.int32), jnp.zeros_like(acc)))
-    return out
+def _shift_up(x: jax.Array, d: int = 1) -> jax.Array:
+    """Move every limb d places up (limb k -> k + d); the top d drop."""
+    return jnp.pad(x[:, :-d], ((0, 0), (d, 0)))
 
 
-def _carry2d_fold(acc: jax.Array) -> jax.Array:
-    v = acc
-    # coefficients < 2^27; each fold divides the excess by 256, so four
-    # rounds leave v in [0, 256] (the +1 cascade case)
-    for _ in range(4):
-        lo = v & RADIX_MASK
-        hi = v >> RADIX_BITS
-        v = lo + jnp.pad(hi[:, :-1], ((0, 0), (1, 0)))
-    # one-bit carry cascade via carry-lookahead prefix
-    g = (v >> RADIX_BITS).astype(jnp.int32)          # generate (0/1)
-    low = v & RADIX_MASK
-    p = (low == RADIX_MASK).astype(jnp.int32)        # propagate
+def _lookahead(g: jax.Array, p: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """Resolve a one-bit cascade: limb k generates a carry (``g``) or passes
+    on the one it receives (``p``), never both.
 
-    def combine(lhs, rhs):
-        g1, p1 = lhs
-        g2, p2 = rhs
-        return g2 | (p2 & g1), p1 & p2
-
-    g_pre, _ = jax.lax.associative_scan(combine, (g, p), axis=1)
-    # carry INTO limb k = combined generate of limbs [0, k-1]
-    c_in = jnp.pad(g_pre[:, :-1], ((0, 0), (1, 0)))
-    return (low + c_in) & RADIX_MASK
+    The carry out of limb k is the generate bit of the last limb at or
+    below k that does not pass one on.  Keyed ``2(k+1) + g`` there (0 where
+    ``p``), that is the low bit of a running maximum over the limb axis:
+    one ``lax.cummax``, which XLA lowers on TPU to a ``reduce_window``.
+    Timed on a TPU v5e, a Kogge-Stone doubling of the same maximum was a
+    few percent faster inside ``montmul2d``, but it moved the Barrett
+    mulmod's convolution accumulators to a limb-minor layout at batches of
+    128 rows and more, which slowed the rows ladders far more than that;
+    the cummax leaves every loop's layout as the sequential carries had it.
+    Returns the carry INTO each limb (B, L) and the carry out of the top
+    limb (B,).
+    """
+    pos = 2 * jnp.arange(1, g.shape[1] + 1, dtype=jnp.int32)
+    key = jnp.where(p, 0, pos + g.astype(jnp.int32))
+    c_out = jax.lax.cummax(key, axis=1) & 1
+    return _shift_up(c_out), c_out[:, -1]
 
 
 def carry2d(acc: jax.Array) -> jax.Array:
-    """Exact carry propagation of int32 coefficients to base 256.
+    """Exact carry propagation of int32 coefficients in [0, 2^31) to base 256.
 
     Overflow past the last limb is dropped (callers size outputs to avoid
     information loss).
     """
-    if CARRY_IMPL == "fold":
-        return _carry2d_fold(acc)
-    return _carry2d_seq(acc)
+    v = acc
+    for _ in range(_N_FOLDS):
+        v = (v & RADIX_MASK) + _shift_up(v >> RADIX_BITS)
+    c_in, _ = _lookahead(v == RADIX, v == RADIX_MASK)
+    return (v + c_in) & RADIX_MASK
 
 
-def add2d(a: jax.Array, b: jax.Array) -> jax.Array:
-    return carry2d(a + b)
+def _sub_borrow(a: jax.Array, b: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """a - b mod 256^L for limbs in [0, 255], and the borrow out (B,) of the
+    top limb, which is 1 exactly when a < b."""
+    d = a - b
+    b_in, b_top = _lookahead(d < 0, d == 0)
+    return (d - b_in) & RADIX_MASK, b_top
 
 
 def sub2d(a: jax.Array, b: jax.Array) -> jax.Array:
-    """a - b mod 256^L (wrap-around)."""
-    bsz, nl = a.shape
-    diff = a - b
-
-    def step(i, st):
-        c, out = st
-        t = jax.lax.dynamic_slice(diff, (0, i), (bsz, 1))[:, 0] + c
-        borrow = (t < 0).astype(jnp.int32)
-        out = jax.lax.dynamic_update_slice(
-            out, (t + (borrow << RADIX_BITS))[:, None], (0, i))
-        return -borrow, out
-
-    _, out = jax.lax.fori_loop(
-        0, nl, step, (jnp.zeros((bsz,), jnp.int32), jnp.zeros_like(a)))
-    return out
-
-
-def cmp2d(a: jax.Array, b: jax.Array) -> jax.Array:
-    """(B,) sign of a - b as big ints."""
-    d = jnp.sign(a - b)
-    bsz, nl = a.shape
-
-    def step(i, c):
-        x = jax.lax.dynamic_slice(d, (0, i), (bsz, 1))[:, 0]
-        return jnp.where(x != 0, x, c)
-
-    return jax.lax.fori_loop(0, nl, step, jnp.zeros((bsz,), jnp.int32))
+    """a - b mod 256^L (wrap-around); limbs in [0, 255]."""
+    return _sub_borrow(a, b)[0]
 
 
 # Convolution strategy (§Perf iteration log):
@@ -179,31 +147,38 @@ def _mul2d_matmul(a: jax.Array, b: jax.Array) -> jax.Array:
     return jnp.einsum("bi,bif->bf", a, toep)
 
 
+def conv2d(a: jax.Array, b: jax.Array) -> jax.Array:
+    """Limb convolution (B, La) x (B, Lb) -> (B, La + Lb), coefficients left
+    unnormalised: each is at most min(La, Lb) * 255^2."""
+    return (_mul2d_matmul(a, b) if MUL_IMPL == "matmul"
+            else _mul2d_loop(a, b))
+
+
 def mul2d(a: jax.Array, b: jax.Array, out_limbs: int) -> jax.Array:
     """Exact limb convolution (B, La) x (B, Lb) -> (B, out_limbs), base 256.
 
-    Every coefficient stays < La * 255^2 + carries < 2^31 for La <= 8192,
-    so int32 accumulation is exact in both implementations.
+    Every coefficient stays < La * 255^2 < 2^31 for La <= 8192, so int32
+    accumulation is exact in both implementations.  Carries only move up,
+    so the low ``out_limbs`` limbs need only the low coefficients.
     """
-    bsz, la = a.shape
-    lb = b.shape[1]
-    full = la + lb
-    acc = (_mul2d_matmul(a, b) if MUL_IMPL == "matmul"
-           else _mul2d_loop(a, b))
-    out = carry2d(acc)
+    acc = conv2d(a, b)
+    full = acc.shape[1]
     if out_limbs <= full:
-        return out[:, :out_limbs]
-    return jnp.pad(out, ((0, 0), (0, out_limbs - full)))
+        return carry2d(acc[:, :out_limbs])
+    return jnp.pad(carry2d(acc), ((0, 0), (0, out_limbs - full)))
 
 
 def cond_sub2d(r: jax.Array, m: jax.Array) -> jax.Array:
-    """r - m if r >= m else r; m broadcast/padded to r's width."""
+    """r - m if r >= m else r; m broadcast/padded to r's width.
+
+    The comparison is the borrow out of ``r - m``: none means r >= m.
+    """
     if m.shape[1] < r.shape[1]:
         m = jnp.pad(m, ((0, 0), (0, r.shape[1] - m.shape[1])))
     if m.shape[0] == 1 and r.shape[0] != 1:
         m = jnp.broadcast_to(m, r.shape)
-    geq = (cmp2d(r, m) >= 0)[:, None]
-    return jnp.where(geq, sub2d(r, m), r)
+    diff, borrow = _sub_borrow(r, m)
+    return jnp.where(borrow[:, None] == 1, r, diff)
 
 
 def barrett2d(x: jax.Array, m: jax.Array, mu: jax.Array) -> jax.Array:

@@ -1,11 +1,13 @@
 """Montgomery-form radix-256 limb kernels (REDC) — the fast reduce path.
 
 Barrett reduction (``common.barrett2d``) costs two extra full convolutions
-(``q2 = q1*mu``, ``r2 = q3*m``) plus two ``cmp2d``-gated conditional
-subtractions per mulmod.  Montgomery multiplication replaces all of that
-with a single L-step REDC sweep interleaving the inverse-limb multiply and
-carry, so a mulmod is one convolution + one REDC + one conditional subtract
-— roughly half the sequential work per step on the CPU/VPU path.
+(``q2 = q1*mu``, ``r2 = q3*m``) plus two conditional subtractions per
+mulmod.  Montgomery multiplication replaces all of that with a single
+L-step REDC sweep interleaving the inverse-limb multiply and carry, so a
+mulmod is one convolution + one REDC + one conditional subtract.  Those
+two L-step loops are the only serial chains of a multiply: carries,
+borrows and the subtract's comparison resolve in constant depth
+(``common.carry2d``, ``common.cond_sub2d``).
 
 Representation (same as ``common.py``): little-endian radix-2^8 limbs in
 int32, 2-D blocks ``(B, L)``.  With ``R = 256^L``:
@@ -18,10 +20,15 @@ REDC correctness bound: for ``t < R*m`` the unreduced output is ``< 2m``,
 so exactly one conditional subtract normalizes it.  Every call site below
 satisfies ``t < R*m`` because at least one convolution operand is ``< m``.
 
-Overflow bound: the sweep adds at most ``L-1`` partial products
-``u*m[j] <= 255*255`` into any coefficient, so coefficients stay below
-``255 + (L-1)*65025 + 2^17 < 2^27`` for ``L <= 2064`` — exact in int32 and
-within ``carry2d``'s fold-variant contract (DESIGN.md §2 headroom note).
+Overflow bound: ``montmul2d`` hands the convolution's coefficients to the
+sweep unnormalised.  Each is at most ``L*255^2``; the sweep adds at most
+``L-1`` partial products ``u*m[j] <= 255^2`` into any coefficient, and the
+running carry stays below ``2L*255``, so every coefficient the sweep reads
+is below ``(2L-1)*255^2 + 2L*255 < 2L*255*256`` -- under ``2^25`` at
+``L = 256``, and exact in int32 for ``L <= 16384``.  The sweep needs of
+limb i only its value mod 256 and an exact ``>> 8``, both of which an
+unnormalised coefficient gives; ``common.carry2d`` then normalises the
+high half once.
 
 The exponent ladders mirror ``common.modexp2d``/``modexp2d_win4`` with the
 Barrett mulmod swapped for ``montmul2d`` (the ``REPRO_REDUCE_IMPL`` knob in
@@ -68,8 +75,9 @@ def _bcast_m(m: jax.Array, bsz: int) -> jax.Array:
 def redc2d(t: jax.Array, m: jax.Array, mp) -> jax.Array:
     """t (B, <=2L) * R^{-1} mod m -> (B, L); requires t < R*m, m odd.
 
-    ``m`` is (1, L) or per-row (B, L); ``mp`` (``-m^{-1} mod 256``) is an
-    int or a per-row (B,) array to match.
+    ``t``'s coefficients may be unnormalised (the overflow bound in the
+    module docstring).  ``m`` is (1, L) or per-row (B, L); ``mp``
+    (``-m^{-1} mod 256``) is an int or a per-row (B,) array to match.
 
     One sequential sweep of L steps: step i zeroes limb i by adding
     ``u = (t[i] * mp) & 0xFF`` copies of m at position i, carrying through
@@ -86,7 +94,7 @@ def redc2d(t: jax.Array, m: jax.Array, mp) -> jax.Array:
     def step(i, st):
         c, acc = st
         v = jax.lax.dynamic_slice(acc, (0, i), (bsz, 1))[:, 0] + c
-        u = (v * mp) & cm.RADIX_MASK
+        u = ((v & cm.RADIX_MASK) * mp) & cm.RADIX_MASK
         c2 = (v + u * m0) >> cm.RADIX_BITS             # low limb is now 0
         if L > 1:
             seg = jax.lax.dynamic_slice(acc, (0, i + 1), (bsz, L - 1))
@@ -105,9 +113,10 @@ def redc2d(t: jax.Array, m: jax.Array, mp) -> jax.Array:
 
 
 def montmul2d(a: jax.Array, b: jax.Array, m: jax.Array, mp: int) -> jax.Array:
-    """mont-domain product a*b*R^{-1} mod m; (B, L) x (B, L) -> (B, L)."""
-    L = m.shape[1]
-    return redc2d(cm.mul2d(a, b, 2 * L), m, mp)
+    """mont-domain product a*b*R^{-1} mod m; (B, L) x (B, L) -> (B, L).
+
+    The convolution goes to REDC unnormalised, with no carry between."""
+    return redc2d(cm.conv2d(a, b), m, mp)
 
 
 def to_mont2d(x: jax.Array, m: jax.Array, mp: int, r2: jax.Array) -> jax.Array:

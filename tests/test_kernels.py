@@ -79,9 +79,9 @@ def test_fft_reference_matches_exact():
     assert (np.asarray(exact) == np.asarray(fft)).all()
 
 
-def test_carry_normalization_extremes():
+@pytest.mark.parametrize("L", [64, 256])
+def test_carry_normalization_extremes(L):
     """Max-coefficient inputs: the int32 accumulation headroom claim."""
-    L = 64
     a8 = jnp.full((2, L), 255, jnp.int32)
     out = cm.mul2d(a8, a8, 2 * L)
     a_int = (256 ** L - 1) // 255 * 255   # value with all limbs 255
@@ -91,6 +91,74 @@ def test_carry_normalization_extremes():
     for i in range(2 * L - 1, -1, -1):
         got = (got << 8) | int(arr[0, i])
     assert got == want
+
+
+# ---------------------------------------------------------------------------
+# constant-depth carries, borrows and comparisons vs Python ints
+# ---------------------------------------------------------------------------
+
+CARRY_LIMBS = [1, 2, 128, 256, 257, 514]
+
+
+def _int_of(row) -> int:
+    return sum(int(v) << (8 * i) for i, v in enumerate(row))
+
+
+def _ints(arr) -> list[int]:
+    return [_int_of(row) for row in np.asarray(arr)]
+
+
+def _rows(vals, L):
+    return jnp.asarray([[(v >> (8 * i)) & 0xFF for i in range(L)]
+                        for v in vals], jnp.int32)
+
+
+@pytest.mark.parametrize("L", CARRY_LIMBS)
+def test_carry2d_exact(L):
+    """carry2d == the coefficients' value mod 256^L, on the inputs that
+    stress a lookahead: a carry run across the whole vector, one broken
+    at the top limb, all limbs 255, and the largest int32 coefficients."""
+    rng = np.random.default_rng(L)
+    top = [255] * (L - 1)
+    coeffs = [
+        rng.integers(0, 2 ** 31, L),            # anything int32 holds
+        rng.integers(0, 2 ** 25, L),            # a montmul's high half
+        [255] * L,                              # all limbs 255, no carry
+        [256] + top,                            # a run across the whole
+        ([256] + top[:-1] + [254]) if L > 1 else [254],   # broken at top
+        [2 ** 31 - 1] * L,
+        [0] * L,
+    ]
+    acc = jnp.asarray(np.asarray(coeffs, np.int64), jnp.int32)
+    want = [sum(int(c) << (8 * i) for i, c in enumerate(row)) % 256 ** L
+            for row in coeffs]
+    assert _ints(cm.carry2d(acc)) == want
+
+
+@pytest.mark.parametrize("L", CARRY_LIMBS)
+def test_sub2d_exact(L):
+    """sub2d == (a - b) mod 256^L, with borrow runs across the whole
+    vector (0 - 1) and broken at the top limb (256^(L-1) - 1)."""
+    R = 256 ** L
+    rng = random.Random(L)
+    pairs = [(rng.randrange(R), rng.randrange(R)) for _ in range(3)]
+    pairs += [(0, 1), (R - 1, R - 1), (R - 1, 0), (0, R - 1),
+              (R // 256, 1), (R // 256 * 255, R // 256 * 255 - 1)]
+    a, b = zip(*pairs)
+    got = _ints(cm.sub2d(_rows(a, L), _rows(b, L)))
+    assert got == [(x - y) % R for x, y in pairs]
+
+
+@pytest.mark.parametrize("L", CARRY_LIMBS)
+def test_cond_sub2d_exact(L):
+    """cond_sub2d at r in {0, m-1, m, 2m-1} (r one limb wider than m, as
+    in REDC), for a random m and the all-255 one."""
+    rng = random.Random(L + 7)
+    for m in (rng.getrandbits(8 * L) | 1 | (1 << (8 * L - 1)),
+              256 ** L - 1):
+        rs = [0, m - 1, m, 2 * m - 1, m + 1]
+        got = _ints(cm.cond_sub2d(_rows(rs, L + 1), _rows([m], L)))
+        assert got == [r - m if r >= m else r for r in rs], m
 
 
 def test_kernel_vs_gold_paillier_roundtrip():

@@ -138,6 +138,77 @@ def test_redc_round_trip_identities():
         assert got2 == [v * v % m for v in vals], m
 
 
+def _rows8(vals, L8):
+    return jnp.asarray([[(v >> (8 * i)) & 0xFF for i in range(L8)]
+                        for v in vals], jnp.int32)
+
+
+@pytest.mark.parametrize("L8", [257, 514])
+def test_redc_takes_unnormalised_convolution_at_its_bound(L8):
+    """montmul2d hands REDC the convolution with no carry between: with all
+    limbs 255 every coefficient is at its bound L*255^2, and the result
+    still equals a*b*R^-1 mod m (m all 255, and one just under R)."""
+    from repro.kernels import common as cm
+    R = 256 ** L8
+    ones = [R - 1] * 2
+    conv = np.asarray(cm.conv2d(_rows8(ones, L8), _rows8(ones, L8)))
+    assert conv.max() == L8 * 255 ** 2
+    rng = random.Random(L8)
+    for m in (R - 1, R - 3):
+        mp = mg.mont_constants(m, L8)[0]
+        a = [R - 1, m - 1, rng.randrange(m)]
+        b = [R - 1, m - 1, rng.randrange(m)]
+        got = mg.montmul2d(_rows8(a, L8), _rows8(b, L8), _rows8([m], L8),
+                           mp)
+        r_inv = pow(R, -1, m)
+        want = [x * y * r_inv % m for x, y in zip(a, b)]
+        assert [sum(int(v) << (8 * i) for i, v in enumerate(row))
+                for row in np.asarray(got)] == want, m
+
+
+def _loops(jaxpr) -> list[tuple[str, object]]:
+    """Every loop primitive (scan or while) in a jaxpr, nested ones too,
+    with its trip count (a scan's length; None for a while)."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name in ("scan", "while"):
+            found.append((eqn.primitive.name, eqn.params.get("length")))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _loops(sub)
+    return found
+
+
+def test_montmul_serial_chains_are_the_convolution_and_the_sweep():
+    """A Montgomery multiply at L = 256 walks exactly two per-limb loops,
+    the convolution and the REDC sweep, each of L steps; the carries, the
+    borrow and the conditional subtract's comparison hold none."""
+    from repro.kernels import common as cm
+    L8, B = 256, 4
+    x = jnp.zeros((B, L8), jnp.int32)
+    m = jnp.ones((1, L8), jnp.int32)
+    mont = jax.make_jaxpr(lambda a, b, mm: mg.montmul2d(a, b, mm, 1))(
+        x, x, m)
+    assert _loops(mont.jaxpr) == [("scan", L8), ("scan", L8)]
+    wide = jnp.zeros((B, 2 * L8), jnp.int32)
+    for fn, args in ((cm.carry2d, (wide,)), (cm.sub2d, (x, x)),
+                     (cm.cond_sub2d, (x, m))):
+        assert _loops(jax.make_jaxpr(fn)(*args).jaxpr) == [], fn.__name__
+
+
+def test_time_montmul_script_checks_and_reports(tmp_path):
+    """The off-benchmark multiply timer runs at a tiny width, checks its
+    chain against Python ints and writes one reading per batch."""
+    import json
+    from scripts import time_montmul
+    out = tmp_path / "montmul.json"
+    assert time_montmul.main(["--limbs", "3", "--batches", "1,2",
+                              "--iters", "5", "--reps", "1",
+                              "--json", str(out)]) == 0
+    rows = json.loads(out.read_text())["rows"]
+    assert [(r["limbs"], r["batch"]) for r in rows] == [(3, 1), (3, 2)]
+    assert all(r["us_per_montmul"] > 0 for r in rows)
+
+
 # ---------------------------------------------------------------------------
 # ops-layer cache + validation regressions
 # ---------------------------------------------------------------------------
