@@ -59,12 +59,10 @@ def _bench_key(rows: list, bits: int, batch: int, repeat: int) -> None:
 
     def launch(op, impl):
         if op == "mulmod":
-            return kops.mulmod(b16, b16, pack, backend="ref")
+            return kops.mulmod(b16, b16, pack)
         if op == "modexp":
-            return kops.modexp(b16, e16, pack, backend="ref",
-                               reduce_impl=impl)
-        return kops.modexp_fixed(b16, e_fix, pack, backend="ref",
-                                 reduce_impl=impl)
+            return kops.modexp(b16, e16, pack, reduce_impl=impl)
+        return kops.modexp_fixed(b16, e_fix, pack, reduce_impl=impl)
 
     walls: dict[tuple, float] = {}
     for op in ("mulmod", "modexp", "modexp_fixed"):

@@ -177,12 +177,10 @@ def _reduce_impl_micro(rows: list) -> dict:
 
     def launch(op, impl):
         if op == "mulmod":
-            return kops.mulmod(b16, b16, pack, backend="ref")
+            return kops.mulmod(b16, b16, pack)
         if op == "modexp":
-            return kops.modexp(b16, e16, pack, backend="ref",
-                               reduce_impl=impl)
-        return kops.modexp_fixed(b16, e_fix, pack, backend="ref",
-                                 reduce_impl=impl)
+            return kops.modexp(b16, e16, pack, reduce_impl=impl)
+        return kops.modexp_fixed(b16, e_fix, pack, reduce_impl=impl)
 
     out = {"batch": B, "key_bits": GOLD_KEY_BITS,
            "modulus_bits": pack.m_int.bit_length(),

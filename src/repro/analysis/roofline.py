@@ -147,11 +147,6 @@ PEAK_LIMB_MULS_PER_S = 4e9
 GAMMA2_EXP_BITS = 20           # typical Gamma_2 exponent width (~log2 Delta)
 
 
-def _active_method() -> str:
-    import os
-    return os.environ.get("REPRO_MODEXP_METHOD", "win4")
-
-
 def _active_reduce_impl() -> str:
     import os
     return os.environ.get("REPRO_REDUCE_IMPL", "montgomery")
@@ -192,10 +187,10 @@ def limb_ops(ops: dict, key_bits: int,
     ``ops`` is the RunReport ``"ops"`` section: ``{phase: {op: count}}``.
     Ciphertexts live mod n^2, i.e. ``L = ceil(2*key_bits / 16)`` limbs.
     Schoolbook costs per op, priced by the ACTIVE ladder schedule
-    (``method`` defaults to ``$REPRO_MODEXP_METHOD``/win4 and
-    ``reduce_impl`` to ``$REPRO_REDUCE_IMPL``/montgomery — the same
-    defaults ``kernels/ops.py`` resolves, so the accounting tracks what
-    actually ran):
+    (``method`` defaults to win4 and ``reduce_impl`` to
+    ``$REPRO_REDUCE_IMPL``/montgomery — the same defaults
+    ``kernels/ops.py`` resolves, so the accounting tracks what actually
+    ran):
 
     * ``mulmod``  — one LxL product: ``L^2``;
     * ``modexp``  — :func:`ladder_mulmods`(method, exp_bits) ``* L^2``;
@@ -203,7 +198,7 @@ def limb_ops(ops: dict, key_bits: int,
       with a key-constant exponent, so the fixed-window schedule applies:
       :func:`ladder_mulmods`("fixed", key_bits) ``* L^2``.
     """
-    method = method or _active_method()
+    method = method or "win4"
     reduce_impl = reduce_impl or _active_reduce_impl()
     L = max(1, -(-2 * key_bits // LIMB_BITS))
     totals: dict[str, int] = {}

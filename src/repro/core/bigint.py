@@ -180,8 +180,8 @@ def compare(a: jax.Array, b: jax.Array) -> jax.Array:
 
 
 # ---------------------------------------------------------------------------
-# Multiplication: exact limb convolution (shift-and-add; MXU-shaped in the
-# Pallas kernel, see kernels/limb_mulmod.py)
+# Multiplication: exact limb convolution (shift-and-add; the kernels'
+# radix-256 form is kernels/common.py)
 # ---------------------------------------------------------------------------
 
 def mul(a: jax.Array, b: jax.Array, out_limbs: int | None = None) -> jax.Array:

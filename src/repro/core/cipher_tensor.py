@@ -144,8 +144,7 @@ def _pack(modulus: int) -> ops.ModulusPack:
     return ops.pack_modulus(modulus)
 
 
-def modexp_mod_vec(base: int, exps: Sequence[int], modulus: int,
-                   backend: str | None = None) -> list[int]:
+def modexp_mod_vec(base: int, exps: Sequence[int], modulus: int) -> list[int]:
     """``[pow(base, e, modulus) for e in exps]`` as one batched launch.
 
     ``exps`` must be nonnegative (callers reduce mod the group order first,
@@ -162,11 +161,11 @@ def modexp_mod_vec(base: int, exps: Sequence[int], modulus: int,
     bases = np.broadcast_to(bi.from_int(int(base) % pack.m_int, pack.L16),
                             (len(exps), pack.L16))
     out = ops.modexp(jnp.asarray(bases), jnp.asarray(bi.from_ints(exps, le)),
-                     pack, backend=backend)
+                     pack)
     return bi.to_ints(out)
 
 
-def reduce_mod_vec(cs, modulus: int, backend: str | None = None) -> list[int]:
+def reduce_mod_vec(cs, modulus: int) -> list[int]:
     """``[int(c) % modulus for c in cs]`` without per-element host division.
 
     Accepts a :class:`CipherTensor` (reduced straight off the resident limb
@@ -183,4 +182,4 @@ def reduce_mod_vec(cs, modulus: int, backend: str | None = None) -> list[int]:
     if int(limbs.shape[0]) == 0:
         return []
     pack = _pack(int(modulus))
-    return bi.to_ints(pv._reduce_into(limbs, pack, backend))
+    return bi.to_ints(pv._reduce_into(limbs, pack))

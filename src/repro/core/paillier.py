@@ -23,8 +23,7 @@ correctness oracle the batched fast paths are tested against:
 
 Both fast paths run on the ``kernels/`` big-integer kernels: public limb
 radix 2^16 (``core/bigint.py`` layout), kernel-internal radix 2^8, ModExp
-via a 4-bit fixed window by default (``REPRO_MODEXP_METHOD=binary`` for the
-paper's Algorithm-2-style ladder).  Scalar functions below (``encrypt``,
+via a 4-bit fixed window.  Scalar functions below (``encrypt``,
 ``decrypt``, ``modexp_crt``, ``c_mul_const``, vector conveniences
 ``encrypt_vec``/``decrypt_vec``/``make_r_pool``) stay pow-based on purpose:
 they are the gold oracle, not the hot path.

@@ -147,7 +147,6 @@ def _norm_exps(exps, batch: int) -> list[int]:
 
 
 def modexp_crt_limbs(bk: BatchKey, bases: Sequence[int], exps,
-                     backend: str | None = None,
                      fixed: bool = False) -> jnp.ndarray:
     """[b^e mod n^2] as (B, L16(n^2)) limbs; ``exps`` scalar or per-element.
 
@@ -159,8 +158,8 @@ def modexp_crt_limbs(bk: BatchKey, bases: Sequence[int], exps,
     ``fixed=True`` opts a SCALAR exponent into the host-known fixed-window
     ladder (``ops.modexp_fixed_pair``): the 4-bit schedules are baked into
     the trace, dropping the per-window oblivious table select, and on the
-    Montgomery ``ref`` path both halves run stacked as ONE ladder over 2B
-    rows (each launch is counted in ``ops.FIXED_CRT``).  Only pass it for
+    Montgomery path both halves run stacked as ONE ladder over 2B rows
+    (each launch is counted in ``ops.FIXED_CRT``).  Only pass it for
     KEY-CONSTANT exponents (enc's ``n``, dec's ``lam``) — every distinct
     exponent value compiles its own executable.  Per-element exponent
     lists ignore the flag.
@@ -184,16 +183,15 @@ def modexp_crt_limbs(bk: BatchKey, bases: Sequence[int], exps,
 
         def fixed_body(bp, bq):
             xp, xq = ops.modexp_fixed_pair(bp, ep_s, vk.pack_p2,
-                                           bq, eq_s, vk.pack_q2,
-                                           backend=backend)
-            return pv.crt_combine_batch(vk, xp, xq, backend=backend)
+                                           bq, eq_s, vk.pack_q2)
+            return pv.crt_combine_batch(vk, xp, xq)
 
         # the reduce impl resolves when the body TRACES, so it is part of
         # the cache identity — else flipping REPRO_REDUCE_IMPL mid-process
         # would silently replay the other impl's executable
-        fn = pv._cached_jit(vk, ("crt_modexp_fixed", backend, ep_s, eq_s,
+        fn = pv._cached_jit(vk, ("crt_modexp_fixed", ep_s, eq_s,
                                  ops.active_reduce_impl()), fixed_body)
-        ops.count_fixed_crt(vk.pack_p2, vk.pack_q2, backend)
+        ops.count_fixed_crt(vk.pack_p2, vk.pack_q2)
         return fn(*_shard_batch(bp, bq))
 
     le = max(1, max(bi.n_limbs_for(e) for e in ep + eq))
@@ -202,18 +200,17 @@ def modexp_crt_limbs(bk: BatchKey, bases: Sequence[int], exps,
         # the whole half-space ladder + eq. (38) recombination compiles to
         # ONE executable per (batch, exponent-width) shape — running the
         # combine eagerly costs ~10x in per-op dispatch
-        xp = ops.modexp(bp, ep, vk.pack_p2, backend=backend)
-        xq = ops.modexp(bq, eq, vk.pack_q2, backend=backend)
-        return pv.crt_combine_batch(vk, xp, xq, backend=backend)
+        xp = ops.modexp(bp, ep, vk.pack_p2)
+        xq = ops.modexp(bq, eq, vk.pack_q2)
+        return pv.crt_combine_batch(vk, xp, xq)
 
     fn = pv._cached_jit(
-        vk, ("crt_modexp", backend, ops.active_reduce_impl()), body)
+        vk, ("crt_modexp", ops.active_reduce_impl()), body)
     return fn(*_shard_batch(bp, bi.from_ints(ep, le),
                             bq, bi.from_ints(eq, le)))
 
 
 def modexp_crt_limbs_in(bk: BatchKey, base_limbs: jnp.ndarray, exps,
-                        backend: str | None = None,
                         fixed: bool = False) -> jnp.ndarray:
     """:func:`modexp_crt_limbs` for bases already resident in limb form.
 
@@ -236,17 +233,16 @@ def modexp_crt_limbs_in(bk: BatchKey, base_limbs: jnp.ndarray, exps,
         ep_s, eq_s = scalar_e % key.phi_p2, scalar_e % key.phi_q2
 
         def fixed_body(c):
-            cp = pv._reduce_into(c, vk.pack_p2, backend)
-            cq = pv._reduce_into(c, vk.pack_q2, backend)
+            cp = pv._reduce_into(c, vk.pack_p2)
+            cq = pv._reduce_into(c, vk.pack_q2)
             xp, xq = ops.modexp_fixed_pair(cp, ep_s, vk.pack_p2,
-                                           cq, eq_s, vk.pack_q2,
-                                           backend=backend)
-            return pv.crt_combine_batch(vk, xp, xq, backend=backend)
+                                           cq, eq_s, vk.pack_q2)
+            return pv.crt_combine_batch(vk, xp, xq)
 
         fn = pv._cached_jit(
-            vk, ("crt_modexp_limbs_fixed", backend, ep_s, eq_s,
+            vk, ("crt_modexp_limbs_fixed", ep_s, eq_s,
                  ops.active_reduce_impl()), fixed_body)
-        ops.count_fixed_crt(vk.pack_p2, vk.pack_q2, backend)
+        ops.count_fixed_crt(vk.pack_p2, vk.pack_q2)
         return fn(_shard_batch(base_limbs))
 
     ep = [e % key.phi_p2 for e in exps]
@@ -254,30 +250,27 @@ def modexp_crt_limbs_in(bk: BatchKey, base_limbs: jnp.ndarray, exps,
     le = max(1, max(bi.n_limbs_for(e) for e in ep + eq))
 
     def body(c, ep, eq):
-        cp = pv._reduce_into(c, vk.pack_p2, backend)
-        cq = pv._reduce_into(c, vk.pack_q2, backend)
-        xp = ops.modexp(cp, ep, vk.pack_p2, backend=backend)
-        xq = ops.modexp(cq, eq, vk.pack_q2, backend=backend)
-        return pv.crt_combine_batch(vk, xp, xq, backend=backend)
+        cp = pv._reduce_into(c, vk.pack_p2)
+        cq = pv._reduce_into(c, vk.pack_q2)
+        xp = ops.modexp(cp, ep, vk.pack_p2)
+        xq = ops.modexp(cq, eq, vk.pack_q2)
+        return pv.crt_combine_batch(vk, xp, xq)
 
     fn = pv._cached_jit(
-        vk, ("crt_modexp_limbs", backend, ops.active_reduce_impl()), body)
+        vk, ("crt_modexp_limbs", ops.active_reduce_impl()), body)
     return fn(*_shard_batch(base_limbs, bi.from_ints(ep, le),
                             bi.from_ints(eq, le)))
 
 
 def modexp_crt_vec(bk: BatchKey, bases: Sequence[int], exps,
-                   backend: str | None = None,
                    fixed: bool = False) -> list[int]:
     """Int-in/int-out batched ``pow(b, e, n^2)`` (see modexp_crt_limbs)."""
     if not len(bases):
         return []
-    return bi.to_ints(modexp_crt_limbs(bk, bases, exps, backend=backend,
-                                       fixed=fixed))
+    return bi.to_ints(modexp_crt_limbs(bk, bases, exps, fixed=fixed))
 
 
 def pow_c_vec(bk: BatchKey, cs, ks,
-              backend: str | None = None,
               fixed: bool = False) -> list[int]:
     """Batched plaintext-constant multiply ⊗: [c^k mod n^2] elementwise.
 
@@ -289,12 +282,11 @@ def pow_c_vec(bk: BatchKey, cs, ks,
     varying scalars would compile one executable per value.
     """
     if isinstance(cs, CipherTensor):
-        return pow_c_ct(bk, cs, ks, backend=backend, fixed=fixed).to_ints()
-    return modexp_crt_vec(bk, cs, ks, backend=backend, fixed=fixed)
+        return pow_c_ct(bk, cs, ks, fixed=fixed).to_ints()
+    return modexp_crt_vec(bk, cs, ks, fixed=fixed)
 
 
 def pow_c_ct(bk: BatchKey, cs: CipherTensor, ks,
-             backend: str | None = None,
              fixed: bool = False) -> CipherTensor:
     """Limb-in/limb-out ⊗ over a resident ciphertext batch.
 
@@ -304,19 +296,15 @@ def pow_c_ct(bk: BatchKey, cs: CipherTensor, ks,
     exps = _norm_exps(ks, B)
     if any(e < 0 for e in exps):   # host base inversion: materialize once
         return CipherTensor(
-            bk, modexp_crt_limbs(bk, cs.to_ints(), ks, backend=backend,
-                                 fixed=fixed))
-    return CipherTensor(
-        bk, modexp_crt_limbs_in(bk, cs.limbs, ks, backend=backend,
-                                fixed=fixed))
+            bk, modexp_crt_limbs(bk, cs.to_ints(), ks, fixed=fixed))
+    return CipherTensor(bk, modexp_crt_limbs_in(bk, cs.limbs, ks, fixed=fixed))
 
 
 # ---------------------------------------------------------------------------
 # Encryption / decryption / homomorphic matvec
 # ---------------------------------------------------------------------------
 
-def _enc_ct_impl(bk: BatchKey, ms: list[int], rs: list[int],
-                 backend: str | None = None) -> CipherTensor:
+def _enc_ct_impl(bk: BatchKey, ms: list[int], rs: list[int]) -> CipherTensor:
     """g=n+1 encryption entirely in limb space: c = (1 + m n) * r^n mod n^2.
 
     r^n runs through the CRT half spaces; the (1 + m n) affine lift and the
@@ -324,7 +312,7 @@ def _enc_ct_impl(bk: BatchKey, ms: list[int], rs: list[int],
     limb-resident (no host ring multiplies, no to_ints)."""
     key, vk = bk.key, bk.vk
     Ln, L2 = vk.pack_n.L16, vk.pack_n2.L16
-    rn = modexp_crt_limbs(bk, rs, key.n, backend=backend, fixed=True)
+    rn = modexp_crt_limbs(bk, rs, key.n, fixed=True)
     m_limbs = bi.from_ints([m % key.n for m in ms], Ln)
 
     def body(m_limbs, rn):
@@ -332,14 +320,20 @@ def _enc_ct_impl(bk: BatchKey, ms: list[int], rs: list[int],
                                  (m_limbs.shape[0], L2))
         gm = bi.mul(m_limbs, n_row, out_limbs=L2)      # m*n < n^2, exact
         gm = bi.add(gm, jnp.zeros_like(gm).at[..., 0].set(1))  # 1 + m n
-        return ops.mulmod(gm, rn, vk.pack_n2, backend=backend)
+        return ops.mulmod(gm, rn, vk.pack_n2)
 
-    fn = pv._cached_jit(vk, f"enc_gold_{backend}", body)
+    fn = pv._cached_jit(vk, "enc_gold", body)
     return CipherTensor(bk, fn(jnp.asarray(m_limbs), rn))
 
 
+def _no_backend(backend) -> None:
+    if backend is not None:
+        raise ValueError(f"unknown kernel backend {backend!r}; the limb "
+                         "kernels have one")
+
+
 def enc_ct(bk: BatchKey, ms, rng: random.Random,
-           backend: str | None = None) -> CipherTensor:
+           backend: None = None) -> CipherTensor:
     """Batched g=n+1 encryption, limb-out: one launch for all blindings.
 
     Draws r exactly like the scalar loop (same rng stream); the resulting
@@ -347,7 +341,9 @@ def enc_ct(bk: BatchKey, ms, rng: random.Random,
     ``[gold.encrypt_crt(key, m, rand_r(key, rng)) for m in ms]`` —
     including for plaintexts outside [0, n), which ``encrypt_crt`` (unlike
     ``encrypt``) wraps mod n via (n+1)^m = 1 + (m mod n) n  (mod n^2).
+    ``backend`` takes only ``None``; the benchmark's fault fakes pass it.
     """
+    _no_backend(backend)
     key = bk.key
     if key.g != key.n + 1:
         raise NotImplementedError("batched path uses the g = n+1 fast path")
@@ -356,37 +352,32 @@ def enc_ct(bk: BatchKey, ms, rng: random.Random,
         return CipherTensor(bk, jnp.zeros((0, bk.vk.pack_n2.L16), jnp.int32),
                             ints=[])
     rs = rand_r_vec(key, len(ms), rng)
-    return _enc_ct_impl(bk, ms, rs, backend=backend)
+    return _enc_ct_impl(bk, ms, rs)
 
 
-def enc_vec(bk: BatchKey, ms, rng: random.Random,
-            backend: str | None = None) -> list[int]:
+def enc_vec(bk: BatchKey, ms, rng: random.Random) -> list[int]:
     """Int-out form of :func:`enc_ct` (same rng stream, same ciphertexts)."""
-    return enc_ct(bk, ms, rng, backend=backend).to_ints()
+    return enc_ct(bk, ms, rng).to_ints()
 
 
-def add_ct(bk: BatchKey, c1: CipherTensor, c2: CipherTensor,
-           backend: str | None = None) -> CipherTensor:
+def add_ct(bk: BatchKey, c1: CipherTensor, c2: CipherTensor) -> CipherTensor:
     """⊕ on resident batches: elementwise ciphertext product mod n^2.
 
     One batched Barrett mulmod launch; bit-identical to the per-element
     ``(a * b) % n2`` host loop it replaces."""
-    return CipherTensor(bk, ops.mulmod(c1.limbs, c2.limbs, bk.vk.pack_n2,
-                                       backend=backend))
+    return CipherTensor(bk, ops.mulmod(c1.limbs, c2.limbs, bk.vk.pack_n2))
 
 
-def rn_pool_limbs(bk: BatchKey, rs: Sequence[int],
-                  backend: str | None = None) -> jnp.ndarray:
+def rn_pool_limbs(bk: BatchKey, rs: Sequence[int]) -> jnp.ndarray:
     """Blinding pool r -> r^n mod n^2 as (B, L16(n^2)) limbs.
 
     The batched replacement for :func:`gold.make_r_pool` on the ``vec``
     cipher path (which needs the pool in limb form anyway).
     """
-    return modexp_crt_limbs(bk, rs, bk.key.n, backend=backend, fixed=True)
+    return modexp_crt_limbs(bk, rs, bk.key.n, fixed=True)
 
 
-def dec_vec(bk: BatchKey, cs,
-            backend: str | None = None) -> list[int]:
+def dec_vec(bk: BatchKey, cs, backend: None = None) -> list[int]:
     """Batched decryption: c^lam for the whole batch in one CRT launch.
 
     The L(x) = (x-1)/n exact division and the mu multiply stay on the host
@@ -394,21 +385,21 @@ def dec_vec(bk: BatchKey, cs,
     ``[gold.decrypt_crt(key, c) for c in cs]``.  Limb-in: a
     :class:`CipherTensor` decrypts straight off its resident limbs (the
     bases reduce into the half spaces in-graph, no ciphertext to_ints).
+    ``backend`` takes only ``None``; the benchmark's fault fakes pass it.
     """
+    _no_backend(backend)
     key = bk.key
     if isinstance(cs, CipherTensor):
         if not len(cs):
             return []
-        x = bi.to_ints(modexp_crt_limbs_in(bk, cs.limbs, key.lam,
-                                           backend=backend, fixed=True))
+        x = bi.to_ints(modexp_crt_limbs_in(bk, cs.limbs, key.lam, fixed=True))
     else:
-        x = modexp_crt_vec(bk, cs, key.lam, backend=backend, fixed=True)
+        x = modexp_crt_vec(bk, cs, key.lam, fixed=True)
     with span("host:dec_finish"):
         return [(xi - 1) // key.n * key.mu % key.n for xi in x]
 
 
-def matvec_many(bk: BatchKey, Ks, cs_list: Sequence,
-                backend: str | None = None) -> list:
+def matvec_many(bk: BatchKey, Ks, cs_list: Sequence) -> list:
     """Fused homomorphic matvecs: out[b][i] = prod_j cs[b][j]^{Ks[b,i,j]}.
 
     All B*(M, N) exponent blocks flatten into ONE batched CRT ModExp launch
@@ -441,7 +432,7 @@ def matvec_many(bk: BatchKey, Ks, cs_list: Sequence,
         rows = [int(c) for row in cs_list for c in row]  # materializes CTs
         bases = [rows[b * N + j] for b in range(B)
                  for _ in range(M) for j in range(N)]
-        powed = modexp_crt_limbs(bk, bases, exps, backend=backend)
+        powed = modexp_crt_limbs(bk, bases, exps)
     else:
         ep = [e % key.phi_p2 for e in exps]
         eq = [e % key.phi_q2 for e in exps]
@@ -460,15 +451,14 @@ def matvec_many(bk: BatchKey, Ks, cs_list: Sequence,
                 count=False)
 
             def powed_ct_body(c, ep, eq):
-                cp = pv._reduce_into(c, vk.pack_p2, backend)
-                cq = pv._reduce_into(c, vk.pack_q2, backend)
-                xp = ops.modexp(bcast(cp), ep, vk.pack_p2, backend=backend)
-                xq = ops.modexp(bcast(cq), eq, vk.pack_q2, backend=backend)
-                return pv.crt_combine_batch(vk, xp, xq, backend=backend)
+                cp = pv._reduce_into(c, vk.pack_p2)
+                cq = pv._reduce_into(c, vk.pack_q2)
+                xp = ops.modexp(bcast(cp), ep, vk.pack_p2)
+                xq = ops.modexp(bcast(cq), eq, vk.pack_q2)
+                return pv.crt_combine_batch(vk, xp, xq)
 
             powed = pv._cached_jit(
-                vk, ("crt_mv_limbs", backend, M, N,
-                     ops.active_reduce_impl()),
+                vk, ("crt_mv_limbs", M, N, ops.active_reduce_impl()),
                 powed_ct_body)(c_limbs, ep_l, eq_l)
         else:
             rows = [int(c) for row in cs_list for c in row]
@@ -476,27 +466,26 @@ def matvec_many(bk: BatchKey, Ks, cs_list: Sequence,
             bq = bi.from_ints([c % key.q2 for c in rows], vk.pack_q2.L16)
 
             def powed_body(bp, ep, bq, eq):
-                xp = ops.modexp(bcast(bp), ep, vk.pack_p2, backend=backend)
-                xq = ops.modexp(bcast(bq), eq, vk.pack_q2, backend=backend)
-                return pv.crt_combine_batch(vk, xp, xq, backend=backend)
+                xp = ops.modexp(bcast(bp), ep, vk.pack_p2)
+                xq = ops.modexp(bcast(bq), eq, vk.pack_q2)
+                return pv.crt_combine_batch(vk, xp, xq)
 
             bp, bq = _shard_batch(bp, bq, count=False)
             powed = pv._cached_jit(
-                vk, ("crt_mv", backend, M, N, ops.active_reduce_impl()),
+                vk, ("crt_mv", M, N, ops.active_reduce_impl()),
                 powed_body)(bp, ep_l, bq, eq_l)
 
     def tree(powed):
-        return pv.mul_tree(vk, powed.reshape(-1, N, L2), backend=backend)
+        return pv.mul_tree(vk, powed.reshape(-1, N, L2))
 
-    out = pv._cached_jit(vk, f"crt_matvec_tree_{backend}_{N}", tree)(powed)
+    out = pv._cached_jit(vk, f"crt_matvec_tree_{N}", tree)(powed)
     if ct_in:
         return [CipherTensor(bk, out[b * M:(b + 1) * M]) for b in range(B)]
     ints = bi.to_ints(out)
     return [ints[b * M:(b + 1) * M] for b in range(B)]
 
 
-def matvec_vec(bk: BatchKey, K, cs,
-               backend: str | None = None):
+def matvec_vec(bk: BatchKey, K, cs):
     """Single homomorphic matvec (M, N) x (N,) -> (M,), batched kernels.
 
     Returns a :class:`CipherTensor` when ``cs`` is one (limb-resident
@@ -504,15 +493,14 @@ def matvec_vec(bk: BatchKey, K, cs,
     """
     K = np.asarray(K, dtype=object)
     cs = cs if isinstance(cs, CipherTensor) else list(cs)
-    return matvec_many(bk, K[None], [cs], backend=backend)[0]
+    return matvec_many(bk, K[None], [cs])[0]
 
 
 # ---------------------------------------------------------------------------
 # jit compile-cache warmup
 # ---------------------------------------------------------------------------
 
-def warmup(bk: BatchKey, shapes: Sequence,
-           backend: str | None = None) -> dict:
+def warmup(bk: BatchKey, shapes: Sequence) -> dict:
     """Pre-compile the batched-path executables for the given shapes.
 
     XLA compiles one executable per (op, batch shape, exponent width); a
@@ -545,16 +533,16 @@ def warmup(bk: BatchKey, shapes: Sequence,
             ones = CipherTensor.from_ints(bk, [1] * N)
             for val in (3, 1 << 17):   # 1- and 2-limb exponent widths
                 Ks = np.full((B, M, N), val, dtype=object)
-                matvec_many(bk, Ks, [ones] * B, backend=backend)
+                matvec_many(bk, Ks, [ones] * B)
                 calls += 1
         else:
             B = int(shape)
             if B <= 0:
                 continue
-            _enc_ct_impl(bk, [0] * B, [1] * B, backend=backend)
+            _enc_ct_impl(bk, [0] * B, [1] * B)
             ones = CipherTensor.from_ints(bk, [1] * B)
-            dec_vec(bk, ones, backend=backend)
-            add_ct(bk, ones, ones, backend=backend)
+            dec_vec(bk, ones)
+            add_ct(bk, ones, ones)
             calls += 3
     out = {"calls": calls, "seconds": time.perf_counter() - t0}
     from ..obs.metrics import record_profile

@@ -25,7 +25,7 @@ from ..kernels import ops
 jax.config.update("jax_enable_x64", True)
 
 # per-key jitted closures: VecKey holds numpy constants, so we cache one
-# jax.jit per (key-object, op, backend); jax dedups shapes internally.
+# jax.jit per (key-object, op); jax dedups shapes internally.
 _JIT_CACHE: dict = {}
 
 
@@ -94,8 +94,7 @@ def make_vec_key(key: gold.PaillierKey) -> VecKey:
 # Encryption: c = (1 + m n) * r^n mod n^2   (g = n+1 fast path)
 # ---------------------------------------------------------------------------
 
-def encrypt_batch(vk: VecKey, m: jax.Array, rn_limbs: jax.Array,
-                  backend: str | None = None) -> jax.Array:
+def encrypt_batch(vk: VecKey, m: jax.Array, rn_limbs: jax.Array) -> jax.Array:
     """Encrypt int64 plaintexts (B,) with precomputed blindings r^n (B, L).
 
     The r^n pool comes from :func:`gold.make_r_pool` (amortized into T_pre,
@@ -112,17 +111,16 @@ def encrypt_batch(vk: VecKey, m: jax.Array, rn_limbs: jax.Array,
         gm = bi.mul(m_limbs, n_row, out_limbs=L2)      # m*n < n^2, exact
         one = jnp.zeros_like(gm).at[..., 0].set(1)
         gm = bi.add(gm, one)                           # 1 + m n  (< n^2)
-        return ops.mulmod(gm, rn_limbs, vk.pack_n2, backend=backend)
+        return ops.mulmod(gm, rn_limbs, vk.pack_n2)
 
-    return _cached_jit(vk, f"enc_{backend}", body)(m, rn_limbs)
+    return _cached_jit(vk, "enc", body)(m, rn_limbs)
 
 
 # ---------------------------------------------------------------------------
 # Decryption: m = L(c^lam mod n^2) * mu mod n, ModExp via CRT half-spaces
 # ---------------------------------------------------------------------------
 
-def crt_combine_batch(vk: VecKey, xp: jax.Array, xq: jax.Array,
-                      backend: str | None = None) -> jax.Array:
+def crt_combine_batch(vk: VecKey, xp: jax.Array, xq: jax.Array) -> jax.Array:
     """x' (B, Lp2), x'' (B, Lq2) -> x (B, Ln2) per eq. (38).
 
     Shared by the in-graph decryption below and the int-in/int-out gold
@@ -133,7 +131,7 @@ def crt_combine_batch(vk: VecKey, xp: jax.Array, xq: jax.Array,
     Lq = vk.pack_q2.L16
     L2 = vk.pack_n2.L16
     # x' reduced into the q^2 space (x' < p^2 may exceed q^2 when p > q)
-    xp_q = _reduce_into(xp, vk.pack_q2, backend)
+    xp_q = _reduce_into(xp, vk.pack_q2)
     xq_f = _fit(xq, Lq)
     # d = (x'' - x') mod q^2 with wrap-around correction
     neg = (bi.compare(xq_f, xp_q) < 0)[..., None]
@@ -141,7 +139,7 @@ def crt_combine_batch(vk: VecKey, xp: jax.Array, xq: jax.Array,
     q2_row = jnp.broadcast_to(jnp.asarray(vk.pack_q2.m16), d0.shape)
     d = jnp.where(neg, bi.add(d0, q2_row), d0)
     t = ops.mulmod(d, jnp.broadcast_to(jnp.asarray(vk.p2_inv_q2), d.shape),
-                   vk.pack_q2, backend=backend)
+                   vk.pack_q2)
     # x = x' + t * p^2  (exact, < n^2)
     tp2 = bi.mul(t, jnp.broadcast_to(jnp.asarray(vk.p2_limbs), (B, L2)),
                  out_limbs=L2)
@@ -160,8 +158,7 @@ def _one(L: int) -> jax.Array:
     return jnp.zeros((L,), jnp.int32).at[0].set(1)
 
 
-def decrypt_batch(vk: VecKey, c_limbs: jax.Array,
-                  backend: str | None = None) -> jax.Array:
+def decrypt_batch(vk: VecKey, c_limbs: jax.Array) -> jax.Array:
     """Ciphertext limbs (B, Ln2) -> int64 plaintexts (B,).
 
     Narrow legacy form: plaintexts MUST fit 63 bits or they silently
@@ -170,11 +167,10 @@ def decrypt_batch(vk: VecKey, c_limbs: jax.Array,
     Delta — use :func:`decrypt_batch_limbs` and decode the limbs
     losslessly (``bigint.to_ints``), as ``protocol.VecBox`` does.
     """
-    return limbs_to_int64(decrypt_batch_limbs(vk, c_limbs, backend=backend))
+    return limbs_to_int64(decrypt_batch_limbs(vk, c_limbs))
 
 
-def decrypt_batch_limbs(vk: VecKey, c_limbs: jax.Array,
-                        backend: str | None = None) -> jax.Array:
+def decrypt_batch_limbs(vk: VecKey, c_limbs: jax.Array) -> jax.Array:
     """Ciphertext limbs (B, Ln2) -> plaintext limbs (B, Ln), full width.
 
     c^lam is computed in the two half-width spaces (the paper's CRT
@@ -185,25 +181,24 @@ def decrypt_batch_limbs(vk: VecKey, c_limbs: jax.Array,
     # the reduce impl resolves at trace time inside ops.modexp_fixed_pair,
     # so it must be part of the cache identity (env flips retrace, not
     # replay)
-    fn = _cached_jit(vk, ("dec", backend, ops.active_reduce_impl()),
-                     lambda c: _decrypt_impl(vk, c, backend))
-    ops.count_fixed_crt(vk.pack_p2, vk.pack_q2, backend)
+    fn = _cached_jit(vk, ("dec", ops.active_reduce_impl()),
+                     lambda c: _decrypt_impl(vk, c))
+    ops.count_fixed_crt(vk.pack_p2, vk.pack_q2)
     return fn(c_limbs)
 
 
-def _decrypt_impl(vk: VecKey, c_limbs: jax.Array,
-                  backend: str | None = None) -> jax.Array:
+def _decrypt_impl(vk: VecKey, c_limbs: jax.Array) -> jax.Array:
     B = c_limbs.shape[0]
     # reduce c into each half space (eq. 35a-b)
-    cp = _reduce_into(c_limbs, vk.pack_p2, backend)
-    cq = _reduce_into(c_limbs, vk.pack_q2, backend)
+    cp = _reduce_into(c_limbs, vk.pack_p2)
+    cq = _reduce_into(c_limbs, vk.pack_q2)
     # lam is key-constant and host-known, so the fixed-window ladder
     # applies (static schedule, no oblivious table selects)
     lam_p = bi.to_ints(np.asarray(vk.lam_p).reshape(1, -1))[0]
     lam_q = bi.to_ints(np.asarray(vk.lam_q).reshape(1, -1))[0]
     xp, xq = ops.modexp_fixed_pair(cp, lam_p, vk.pack_p2,
-                                   cq, lam_q, vk.pack_q2, backend=backend)
-    x = crt_combine_batch(vk, xp, xq, backend=backend)    # c^lam mod n^2
+                                   cq, lam_q, vk.pack_q2)
+    x = crt_combine_batch(vk, xp, xq)    # c^lam mod n^2
     # alpha = (x - 1) / n  — exact division, multiplicative
     Ln = vk.pack_n.L16
     k_limbs = Ln + 1
@@ -215,10 +210,10 @@ def _decrypt_impl(vk: VecKey, c_limbs: jax.Array,
     # m = alpha * mu mod n (full limb width; wrappers narrow if asked)
     return ops.mulmod(_fit(alpha, Ln),
                       jnp.broadcast_to(jnp.asarray(vk.mu_limbs), (B, Ln)),
-                      vk.pack_n, backend=backend)
+                      vk.pack_n)
 
 
-def _reduce_into(c: jax.Array, pack: ops.ModulusPack, backend) -> jax.Array:
+def _reduce_into(c: jax.Array, pack: ops.ModulusPack) -> jax.Array:
     """Big (B, L) value -> (B, Lpack) reduced mod pack.m via chunked fold.
 
     Splits c into Lpack-limb chunks and folds MSB->LSB with
@@ -236,9 +231,8 @@ def _reduce_into(c: jax.Array, pack: ops.ModulusPack, backend) -> jax.Array:
     acc = jnp.zeros((B, Lp), jnp.int32)
     for i in range(n_chunks - 1, -1, -1):
         # chunk < 2^{16 Lp} may exceed m by a large factor: Barrett it first
-        chunk = ops.mulmod(c[..., i * Lp:(i + 1) * Lp], one, pack,
-                           backend=backend)
-        acc = ops.mulmod(acc, base_l, pack, backend=backend)
+        chunk = ops.mulmod(c[..., i * Lp:(i + 1) * Lp], one, pack)
+        acc = ops.mulmod(acc, base_l, pack)
         s = bi.add(_fit(acc, Lp + 1), _fit(chunk, Lp + 1))   # < 2m
         s = bi._cond_sub(s, m_pad)
         acc = s[..., :Lp]
@@ -249,47 +243,44 @@ def _reduce_into(c: jax.Array, pack: ops.ModulusPack, backend) -> jax.Array:
 # Homomorphic operators (vectorized Definitions 1 & 2)
 # ---------------------------------------------------------------------------
 
-def c_add_batch(vk: VecKey, c1: jax.Array, c2: jax.Array,
-                backend: str | None = None) -> jax.Array:
+def c_add_batch(vk: VecKey, c1: jax.Array, c2: jax.Array) -> jax.Array:
     """Enc(a) ⊕ Enc(b): elementwise ciphertext product mod n^2."""
-    return ops.mulmod(c1, c2, vk.pack_n2, backend=backend)
+    return ops.mulmod(c1, c2, vk.pack_n2)
 
 
-def c_mul_const_batch(vk: VecKey, c: jax.Array, k: jax.Array, exp_limbs: int = 4,
-                      backend: str | None = None) -> jax.Array:
+def c_mul_const_batch(vk: VecKey, c: jax.Array, k: jax.Array,
+                      exp_limbs: int = 4) -> jax.Array:
     """k ⊗ Enc(a): per-element ciphertext^k mod n^2 (k int64 >= 0)."""
     def body(c, k):
-        return ops.modexp(c, int64_to_limbs(k, exp_limbs), vk.pack_n2,
-                          backend=backend)
-    return _cached_jit(vk, f"cmul_{backend}_{exp_limbs}", body)(c, k)
+        return ops.modexp(c, int64_to_limbs(k, exp_limbs), vk.pack_n2)
+    return _cached_jit(vk, f"cmul_{exp_limbs}", body)(c, k)
 
 
-def c_matvec(vk: VecKey, K: jax.Array, c_vec: jax.Array, exp_limbs: int = 4,
-             backend: str | None = None) -> jax.Array:
+def c_matvec(vk: VecKey, K: jax.Array, c_vec: jax.Array,
+             exp_limbs: int = 4) -> jax.Array:
     """Homomorphic matrix-vector product: out[i] = Π_j c_j^{K[i,j]} mod n^2.
 
     This is the edge node's x-hat update (eq. 13): the (M, N) ModExp batch is
     flattened into one kernel launch (the paper's SM-level parallelism), then
     row-reduced with a log-depth tree of batched ciphertext multiplies.
     """
-    return _cached_jit(vk, f"cmv_{backend}_{exp_limbs}_{K.shape}",
-                       lambda K, c: _c_matvec_impl(vk, K, c, exp_limbs,
-                                                   backend))(K, c_vec)
+    return _cached_jit(vk, f"cmv_{exp_limbs}_{K.shape}",
+                       lambda K, c: _c_matvec_impl(vk, K, c, exp_limbs))(
+                           K, c_vec)
 
 
 def _c_matvec_impl(vk: VecKey, K: jax.Array, c_vec: jax.Array,
-                   exp_limbs: int, backend: str | None) -> jax.Array:
+                   exp_limbs: int) -> jax.Array:
     M, N = K.shape
     L2 = vk.pack_n2.L16
     powed = ops.modexp(
         jnp.broadcast_to(c_vec[None, :, :], (M, N, L2)).reshape(M * N, L2),
         int64_to_limbs(K.reshape(-1), exp_limbs),
-        vk.pack_n2, backend=backend).reshape(M, N, L2)
-    return mul_tree(vk, powed, backend=backend)
+        vk.pack_n2).reshape(M, N, L2)
+    return mul_tree(vk, powed)
 
 
-def mul_tree(vk: VecKey, cur: jax.Array, backend: str | None = None
-             ) -> jax.Array:
+def mul_tree(vk: VecKey, cur: jax.Array) -> jax.Array:
     """Log-depth batched ciphertext product over axis 1: (R, N, L) -> (R, L).
 
     Each round halves N with one batched mulmod launch mod n^2; exact
@@ -303,7 +294,7 @@ def mul_tree(vk: VecKey, cur: jax.Array, backend: str | None = None
         a = cur[:, :half]
         b = cur[:, half:2 * half]
         prod = ops.mulmod(a.reshape(R * half, L2), b.reshape(R * half, L2),
-                          vk.pack_n2, backend=backend).reshape(R, half, L2)
+                          vk.pack_n2).reshape(R, half, L2)
         if n_cur % 2:
             prod = jnp.concatenate([prod, cur[:, -1:]], axis=1)
             n_cur = half + 1

@@ -141,15 +141,13 @@ class GoldBox:
 
     def __init__(self, key: gold.PaillierKey, rng: random.Random,
                  crt: bool = True, counter=None, batch: bool = True,
-                 batch_min: int | None = None,
-                 kernel_backend: str | None = None):
+                 batch_min: int | None = None):
         self.key = key
         self.rng = rng
         self.crt = crt
         self.counter = counter or OpCounter()
         self.batch = batch
         self.batch_min = pb.BATCH_MIN if batch_min is None else batch_min
-        self.kernel_backend = kernel_backend
         self._bk: pb.BatchKey | None = None
 
     def batch_key(self) -> pb.BatchKey:
@@ -166,8 +164,7 @@ class GoldBox:
         # appear and disappear with the batch size
         if self.batch and self.crt and flat.size >= self.batch_min \
                 and self.key.g == self.key.n + 1:
-            return pb.enc_ct(self.batch_key(), flat, self.rng,
-                             backend=self.kernel_backend)
+            return pb.enc_ct(self.batch_key(), flat, self.rng)
         enc = gold.encrypt_crt if self.crt else gold.encrypt
         return [enc(self.key, int(x), gold.rand_r(self.key, self.rng))
                 for x in flat]
@@ -176,8 +173,7 @@ class GoldBox:
         self.counter.bump("mulmod", len(c1))
         if self.batch and self.crt and isinstance(c1, CipherTensor) \
                 and isinstance(c2, CipherTensor):
-            return pb.add_ct(self.batch_key(), c1, c2,
-                             backend=self.kernel_backend)
+            return pb.add_ct(self.batch_key(), c1, c2)
         return [(a * b) % self.key.n2 for a, b in zip(c1, c2)]
 
     def matvec(self, K: np.ndarray, c):
@@ -186,8 +182,7 @@ class GoldBox:
         self.counter.bump("modexp", M * N)
         self.counter.bump("mulmod", M * (N - 1))
         if self.batch and self.crt and M * N >= self.batch_min:
-            return pb.matvec_vec(self.batch_key(), Km, c,
-                                 backend=self.kernel_backend)
+            return pb.matvec_vec(self.batch_key(), Km, c)
         out = []
         for i in range(M):
             acc = 1
@@ -199,8 +194,7 @@ class GoldBox:
     def decrypt(self, c) -> np.ndarray:
         self.counter.bump("dec", len(c))
         if self.batch and self.crt and len(c) >= self.batch_min:
-            vals = pb.dec_vec(self.batch_key(), c,
-                              backend=self.kernel_backend)
+            vals = pb.dec_vec(self.batch_key(), c)
         else:
             dec = gold.decrypt_crt if self.crt else gold.decrypt
             vals = [dec(self.key, x) for x in c]
@@ -224,15 +218,13 @@ class VecBox:
     name = "vec"
 
     def __init__(self, key: gold.PaillierKey, rng: random.Random,
-                 backend: str | None = None, counter=None,
-                 plain_bits: int | None = None):
+                 counter=None, plain_bits: int | None = None):
         # share the limb-packed key (and thus the per-VecKey jit caches)
         # with any GoldBox over the same key via the make_batch_key cache
         self._bk = pb.make_batch_key(key)
         self.vk = self._bk.vk
         self.key = key
         self.rng = rng
-        self.backend = backend
         self.counter = counter or OpCounter()
         self.plain_bits = key.n.bit_length() if plain_bits is None \
             else plain_bits
@@ -243,24 +235,22 @@ class VecBox:
             # r^n blinding pool batched through the CRT limb kernels (one
             # launch) instead of per-element Python pow (make_r_pool)
             rs = pb.rand_r_vec(self.key, len(m), self.rng)
-            rn = pb.rn_pool_limbs(self._bk, rs, backend=self.backend)
+            rn = pb.rn_pool_limbs(self._bk, rs)
         else:
             pool = gold.make_r_pool(self.key, len(m), self.rng)
             rn = jnp.asarray(bi.from_ints(pool, self.vk.pack_n2.L16))
         self.counter.bump("enc", len(m))
-        return pv.encrypt_batch(self.vk, jnp.asarray(m.astype(np.int64)), rn,
-                                backend=self.backend)
+        return pv.encrypt_batch(self.vk, jnp.asarray(m.astype(np.int64)), rn)
 
     def add(self, c1, c2):
         self.counter.bump("mulmod", int(c1.shape[0]))
-        return pv.c_add_batch(self.vk, c1, c2, backend=self.backend)
+        return pv.c_add_batch(self.vk, c1, c2)
 
     def matvec(self, K: np.ndarray, c):
         M, N = K.shape
         self.counter.bump("modexp", M * N)
         self.counter.bump("mulmod", M * (N - 1))
-        return pv.c_matvec(self.vk, jnp.asarray(np.asarray(K, np.int64)), c,
-                           backend=self.backend)
+        return pv.c_matvec(self.vk, jnp.asarray(np.asarray(K, np.int64)), c)
 
     def decrypt(self, c) -> np.ndarray:
         """Limb-in decryption with a full-width plaintext return path.
@@ -276,7 +266,7 @@ class VecBox:
         if isinstance(c, CipherTensor):
             c = c.limbs
         self.counter.bump("dec", int(c.shape[0]))
-        m_limbs = pv.decrypt_batch_limbs(self.vk, c, backend=self.backend)
+        m_limbs = pv.decrypt_batch_limbs(self.vk, c)
         if self.plain_bits <= 62:           # every plaintext fits int64
             return np.asarray(pv.limbs_to_int64(m_limbs))
         return np.array(bi.to_ints(np.asarray(m_limbs)), dtype=object)
@@ -338,7 +328,6 @@ class ProtocolConfig:
     key_bits: int = 256
     crt: bool = True
     collaborative: bool = False        # Algorithm 3 master/edge CRT split
-    kernel_backend: str | None = None  # vec/gold-batch cipher kernel backend
     gold_batch: bool = True            # gold cipher: batched CRT fast path
     #   (False = per-element scalar reference; bench_topology records the
     #   measured speedup between the two)
@@ -395,7 +384,6 @@ class EdgeNode:
         # ProtocolConfig.gold_batch via collab_setup; the edge needs no
         # key material for these — only p^2 itself)
         self.collab_batch = False
-        self.collab_backend = None
 
     # -- Initialization phase -------------------------------------------
     def init_phase(self, Qk: np.ndarray, mu: float,
@@ -421,10 +409,9 @@ class EdgeNode:
 
     # -- Algorithm 3: collaborative masked p^2-space ModExp ---------------
     def collab_setup(self, p2: int, phi_p2: int, g: int,
-                     batch: bool = False, backend: str | None = None):
+                     batch: bool = False):
         self.p2, self.phi_p2, self.g_p = p2, phi_p2, g % p2
         self.collab_batch = batch
-        self.collab_backend = backend
 
     def collab_encrypt_half(self, masked_exp: np.ndarray) -> list[int]:
         """g'^{O(Gamma(z)) mod phi(p^2)} mod p^2 for each masked exponent.
@@ -435,8 +422,7 @@ class EdgeNode:
         es = [int(e) % self.phi_p2
               for e in np.asarray(masked_exp).reshape(-1)]
         if self.collab_batch and len(es) >= pb.BATCH_MIN:
-            return ct_mod.modexp_mod_vec(self.g_p, es, self.p2,
-                                         backend=self.collab_backend)
+            return ct_mod.modexp_mod_vec(self.g_p, es, self.p2)
         return self._collab_half_scalar(es)
 
     def _collab_half_scalar(self, es: list[int]) -> list[int]:
@@ -449,11 +435,9 @@ class EdgeNode:
         off its limbs; int lists batch-reduce too under ``gold_batch``
         routing, else fall back to the per-element host ``%`` loop."""
         if isinstance(x_hat, CipherTensor):
-            return ct_mod.reduce_mod_vec(x_hat, self.p2,
-                                         backend=self.collab_backend)
+            return ct_mod.reduce_mod_vec(x_hat, self.p2)
         if self.collab_batch and len(x_hat) >= pb.BATCH_MIN:
-            return ct_mod.reduce_mod_vec(x_hat, self.p2,
-                                         backend=self.collab_backend)
+            return ct_mod.reduce_mod_vec(x_hat, self.p2)
         return self._reduce_p2_scalar(x_hat)
 
     def _reduce_p2_scalar(self, x_hat) -> list[int]:
@@ -490,11 +474,9 @@ def make_box(cfg: ProtocolConfig, n_dim: int, rng: random.Random,
     check_plaintext_fits(key, cfg.spec, n_dim)
     if cfg.cipher == "gold":
         return GoldBox(key, rng, crt=cfg.crt, counter=counter,
-                       batch=cfg.gold_batch,
-                       kernel_backend=cfg.kernel_backend), key
+                       batch=cfg.gold_batch), key
     if cfg.cipher == "vec":
-        return VecBox(key, rng, backend=cfg.kernel_backend,
-                      counter=counter,
+        return VecBox(key, rng, counter=counter,
                       plain_bits=cfg.spec.plaintext_bits(n_dim)), key
     raise ValueError(cfg.cipher)
 
@@ -584,8 +566,7 @@ def run_protocol(A: np.ndarray, y: np.ndarray, cfg: ProtocolConfig,
         u3s.append(wl.share_vector(st, k, Bk))
         if cfg.collaborative and key is not None:
             edge.collab_setup(key.p2, key.phi_p2, key.g,
-                              batch=cfg.gold_batch,
-                              backend=cfg.kernel_backend)
+                              batch=cfg.gold_batch)
 
     # --- Data security sharing phase -------------------------------------
     counter.phase = PHASE_SHARE
@@ -772,8 +753,7 @@ def collaborative_encrypt(key: gold.PaillierKey, edge: EdgeNode,
 
 
 def collab_encrypt_vec(key: gold.PaillierKey, edge: EdgeNode,
-                       m: np.ndarray, rng: random.Random,
-                       backend: str | None = None) -> list[int]:
+                       m: np.ndarray, rng: random.Random) -> list[int]:
     """Whole-batch :func:`collaborative_encrypt`: no Python ``pow`` loops.
 
     Same Remark-4 information flow, same rng stream, bit-identical
@@ -789,12 +769,12 @@ def collab_encrypt_vec(key: gold.PaillierKey, edge: EdgeNode,
     e_half = edge.collab_encrypt_half(masked)
     # --- master side, batched ---
     uns = ct_mod.modexp_mod_vec(key.g, [-t % key.phi_p2 for t in masks],
-                                key.p2, backend=backend)
+                                key.p2)
     gqs = ct_mod.modexp_mod_vec(key.g, [int(x) % key.phi_q2 for x in m],
-                                key.q2, backend=backend)
+                                key.q2)
     bk = pb.make_batch_key(key)
     rs = pb.rand_r_vec(key, len(m), rng)
-    rns = pb.modexp_crt_vec(bk, rs, key.n, backend=backend)
+    rns = pb.modexp_crt_vec(bk, rs, key.n)
     out = []
     for ep, un, gq, rn in zip(e_half, uns, gqs, rns):
         gp = (ep * un) % key.p2                       # g^m mod p^2

@@ -14,7 +14,7 @@ Two layers, both built on the Gamma quantization of §III-A:
    (ciphertext products), only the master decrypts the SUM — individual
    contributions stay hidden (the paper's privacy model applied to FL-style
    gradient exchange). Host-level (runs the gold/vec cipher), validated at
-   toy key sizes; on a real cluster the vec path rides the Pallas kernels.
+   toy key sizes; on a real cluster the vec path rides the limb kernels.
 
 Error-feedback residuals make the compressed path safe for training: the
 quantization error of step t is added back into step t+1's gradient, so the

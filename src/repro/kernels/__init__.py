@@ -1,3 +1,6 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Batched big-integer kernels: radix-256 limb ladders in plain XLA.
+
+``ops`` is the public entry (radix-2^16 limbs in and out); ``common`` holds
+the limb arithmetic and the Barrett ladders, ``montgomery`` the REDC
+ladders, and ``ref`` chooses among them by ladder and reduction.
+"""

@@ -1,4 +1,4 @@
-"""Shared radix-256 limb helpers for the Pallas kernels and their oracle.
+"""Radix-256 limb helpers: the multiplies, carries and ladders of the kernels.
 
 Kernel-internal representation: base-2^8 limbs held in int32. Rationale
 (DESIGN.md §2): TPU vector units have no 64-bit integer path; with 8-bit
@@ -39,8 +39,7 @@ def limbs8_to16(x: jax.Array) -> jax.Array:
 
 
 # ---------------------------------------------------------------------------
-# Radix-256 primitives on 2-D blocks (B, L).  These run both inside
-# pallas_call bodies (interpret mode) and as plain jnp (the oracle).
+# Radix-256 primitives on 2-D blocks (B, L), as plain jnp.
 # ---------------------------------------------------------------------------
 
 # Carries, borrows and comparisons resolve in constant depth: no per-limb

@@ -244,8 +244,7 @@ def modexp2d_mont_fixed(base, win_arr, m, mp, r1, r2, groups=()):
     """Fixed (host-known, per row group) exponent ladder, Montgomery domain.
 
     ``win_arr`` is the (G, n_win) int32 schedule of MSB-first 4-bit windows
-    from :func:`exp_window_rows` (passed as an operand so Pallas kernels
-    don't capture trace constants); ``groups`` is the static tuple of the G
+    from :func:`exp_window_rows`; ``groups`` is the static tuple of the G
     groups' row counts, in row order (``()``: one group, the whole batch).
     ``m``, ``r1`` and ``r2`` are (1, L) or per-row (bsz, L), and ``mp`` an
     int or a per-row (bsz,) array, so each group may have its own modulus.

@@ -2,14 +2,8 @@
 
 Callers hold big integers as radix-2^16 limb arrays (core/bigint.py format);
 these wrappers pack the modulus, convert to the kernels' radix-256 layout,
-pad the batch to block multiples, dispatch to a backend and convert back.
-
-Backends:
-  * ``ref``    — kernels/ref.py jnp oracle (compiled XLA; the fast CPU path)
-  * ``pallas`` — the Pallas kernels, in interpret mode on the CPU only.
-                 They do not lower for the TPU yet: the limb helpers'
-                 ``fori_loop`` + ``dynamic_slice`` have no Pallas TPU
-                 lowering (tests/test_tpu_compile.py pins this).
+run the jitted XLA ladders (``kernels/common.py``, ``kernels/montgomery.py``)
+and convert back.
 
 Reduction (``REPRO_REDUCE_IMPL``, read per call):
   * ``montgomery`` (default) — REDC ladders from kernels/montgomery.py for
@@ -21,11 +15,6 @@ Reduction (``REPRO_REDUCE_IMPL``, read per call):
 Barrett correctness requires the modulus to fill its top radix-256 limb, so
 ``pack_modulus`` sizes L8 to the exact byte length (DESIGN.md §2 note on
 radix re-sizing vs. the paper's b-tilde choice).
-
-Batch padding: batches are padded UP to the canonical ``block_b`` and the
-jit cache is keyed on that canonical size — never on the incoming batch
-size, which under serving/churn workloads varies per round and previously
-grew the cache without bound (one trace per distinct batch < 128).
 """
 from __future__ import annotations
 
@@ -42,14 +31,17 @@ from ..obs.trace import span
 from . import common as cm
 from . import montgomery as mg
 from . import ref as ref_impl
-from .limb_mulmod import mulmod_pallas
-from .modexp import METHODS, REDUCE_IMPLS, modexp_fixed_pallas, modexp_pallas
 
-DEFAULT_BACKEND = os.environ.get("REPRO_KERNEL_BACKEND", "ref")
+METHODS = ("binary", "win4")
+REDUCE_IMPLS = ("barrett", "montgomery")
 
-# jitted-closure cache: keyed by (modulus, backend, op, canonical block /
-# method / reduce impl) — jax.jit dedups shapes internally, so each
-# (op, modulus, shape) traces exactly once.
+# the ladder of ``modexp`` and ``modexp_rows``: read per call, so a test
+# may switch it to ``binary``
+MODEXP_METHOD = "win4"
+
+# jitted-closure cache: keyed by (modulus, op, method / reduce impl) —
+# jax.jit dedups shapes internally, so each (op, modulus, shape) traces
+# exactly once.
 _JIT_CACHE: dict = {}
 
 
@@ -58,10 +50,6 @@ def _cached_jit(key, builder):
     if fn is None:
         fn = _JIT_CACHE[key] = jax.jit(builder)
     return fn
-
-
-def _interpret() -> bool:
-    return jax.default_backend() == "cpu"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -118,14 +106,6 @@ def _to8(x: int, n: int) -> np.ndarray:
     return out
 
 
-def _pad_batch(x: jax.Array, block_b: int) -> tuple[jax.Array, int]:
-    bsz = x.shape[0]
-    rem = (-bsz) % block_b
-    if rem:
-        x = jnp.concatenate([x, jnp.zeros((rem, x.shape[1]), x.dtype)], axis=0)
-    return x, bsz
-
-
 def _to_radix8(x16: jax.Array, L8: int) -> jax.Array:
     x8 = cm.limbs16_to8(x16)
     if x8.shape[-1] >= L8:
@@ -159,37 +139,19 @@ def _resolve_reduce(pack: ModulusPack, reduce_impl: str | None) -> str:
     return impl
 
 
-def mulmod(a16: jax.Array, b16: jax.Array, pack: ModulusPack,
-           backend: str | None = None, block_b: int = 128) -> jax.Array:
+def mulmod(a16: jax.Array, b16: jax.Array, pack: ModulusPack) -> jax.Array:
     """(B, L16) x (B, L16) -> (B, L16): (a*b) mod m."""
-    backend = backend or DEFAULT_BACKEND
     m8 = pack.m8
     mu8 = pack.mu8
     L8, L16 = pack.L8, pack.L16
     if a16.shape[0] == 0:
         return jnp.zeros((0, L16), jnp.int32)
 
-    if backend == "ref":
-        def body(a16, b16):
-            return _to_radix16(
-                ref_impl.mulmod_ref(_to_radix8(a16, L8), _to_radix8(b16, L8),
-                                    jnp.asarray(m8), jnp.asarray(mu8)), L16)
-        return _cached_jit((pack.m_int, "ref", "mulmod"), body)(a16, b16)
-    if backend == "pallas":
-        interp = _interpret()
-
-        def body(a16, b16):
-            a8, bsz = _pad_batch(_to_radix8(a16, L8), block_b)
-            b8, _ = _pad_batch(_to_radix8(b16, L8), block_b)
-            out8 = mulmod_pallas(a8, b8, jnp.asarray(m8), jnp.asarray(mu8),
-                                 block_b=block_b, interpret=interp)[:bsz]
-            return _to_radix16(out8, L16)
-        return _cached_jit((pack.m_int, "pallas", "mulmod", block_b), body)(
-            a16, b16)
-    raise ValueError(f"unknown backend {backend!r}")
-
-
-MODEXP_METHOD = os.environ.get("REPRO_MODEXP_METHOD", "win4")
+    def body(a16, b16):
+        return _to_radix16(
+            ref_impl.mulmod_ref(_to_radix8(a16, L8), _to_radix8(b16, L8),
+                                jnp.asarray(m8), jnp.asarray(mu8)), L16)
+    return _cached_jit((pack.m_int, "mulmod"), body)(a16, b16)
 
 
 def _validate_method(method: str, exp_bits: int) -> None:
@@ -204,7 +166,6 @@ def _validate_method(method: str, exp_bits: int) -> None:
 
 
 def modexp(base16: jax.Array, exp16: jax.Array, pack: ModulusPack,
-           backend: str | None = None, block_b: int = 128,
            method: str | None = None,
            reduce_impl: str | None = None) -> jax.Array:
     """base^exp mod m over a batch; per-element exponents.
@@ -215,7 +176,6 @@ def modexp(base16: jax.Array, exp16: jax.Array, pack: ModulusPack,
     always satisfy this; validated here — the kernel-side assert is a
     trace-time no-op). ``reduce_impl`` overrides ``REPRO_REDUCE_IMPL``.
     """
-    backend = backend or DEFAULT_BACKEND
     method = method or MODEXP_METHOD
     _validate_method(method, exp16.shape[1] * 16)
     impl = _resolve_reduce(pack, reduce_impl)
@@ -230,35 +190,18 @@ def modexp(base16: jax.Array, exp16: jax.Array, pack: ModulusPack,
     if impl == "montgomery":
         mont_args = dict(r1_8=pack.r1_8, r2_8=pack.r2_8, mp=pack.mp8)
 
-    if backend == "ref":
-        def body(base16, exp16):
-            return _to_radix16(
-                ref_impl.modexp_ref(_to_radix8(base16, L8),
-                                    cm.limbs16_to8(exp16),
-                                    jnp.asarray(m8), jnp.asarray(mu8),
-                                    method=method, reduce_impl=impl,
-                                    **mont_args), L16)
-        return _cached_jit((pack.m_int, "ref", "modexp", method, impl),
-                           body)(base16, exp16)
-    if backend == "pallas":
-        interp = _interpret()
-
-        def body(base16, exp16):
-            b8, bsz = _pad_batch(_to_radix8(base16, L8), block_b)
-            e8, _ = _pad_batch(cm.limbs16_to8(exp16), block_b)
-            out8 = modexp_pallas(b8, e8, jnp.asarray(m8), jnp.asarray(mu8),
-                                 block_b=block_b, interpret=interp,
-                                 method=method, reduce_impl=impl,
-                                 **mont_args)[:bsz]
-            return _to_radix16(out8, L16)
-        return _cached_jit(
-            (pack.m_int, "pallas", "modexp", block_b, method, impl),
-            body)(base16, exp16)
-    raise ValueError(f"unknown backend {backend!r}")
+    def body(base16, exp16):
+        return _to_radix16(
+            ref_impl.modexp_ref(_to_radix8(base16, L8),
+                                cm.limbs16_to8(exp16),
+                                jnp.asarray(m8), jnp.asarray(mu8),
+                                method=method, reduce_impl=impl,
+                                **mont_args), L16)
+    return _cached_jit((pack.m_int, "modexp", method, impl),
+                       body)(base16, exp16)
 
 
 def modexp_fixed(base16: jax.Array, e: int, pack: ModulusPack,
-                 backend: str | None = None, block_b: int = 128,
                  reduce_impl: str | None = None) -> jax.Array:
     """base^e mod m with ONE host-known exponent shared across the batch.
 
@@ -275,7 +218,6 @@ def modexp_fixed(base16: jax.Array, e: int, pack: ModulusPack,
     if e < 0:
         raise ValueError("modexp_fixed requires a non-negative exponent; "
                          "invert the base host-side first")
-    backend = backend or DEFAULT_BACKEND
     impl = _resolve_reduce(pack, reduce_impl)
     m8 = pack.m8
     mu8 = pack.mu8
@@ -283,62 +225,39 @@ def modexp_fixed(base16: jax.Array, e: int, pack: ModulusPack,
     if base16.shape[0] == 0:
         return jnp.zeros((0, L16), jnp.int32)
     windows = mg.exp_windows(e)
-    mont_args = {}
-    if impl == "montgomery":    # numpy constants (see modexp note)
-        mont_args = dict(r1_8=pack.r1_8, r2_8=pack.r2_8, mp=pack.mp8)
 
-    if backend == "ref":
-        def body(base16):
-            b8 = _to_radix8(base16, L8)
-            win_arr = jnp.asarray(windows, jnp.int32).reshape(1, -1)
-            if impl == "montgomery":
-                out8 = mg.modexp2d_mont_fixed(
-                    b8, win_arr, jnp.asarray(m8), pack.mp8,
-                    jnp.asarray(pack.r1_8), jnp.asarray(pack.r2_8))
-            else:
-                out8 = mg.modexp2d_fixed_barrett(
-                    b8, win_arr, jnp.asarray(m8), jnp.asarray(mu8))
-            return _to_radix16(out8, L16)
-        return _cached_jit((pack.m_int, "ref", "modexp_fixed", impl, e),
-                           body)(base16)
-    if backend == "pallas":
-        interp = _interpret()
-
-        def body(base16):
-            b8, bsz = _pad_batch(_to_radix8(base16, L8), block_b)
-            out8 = modexp_fixed_pallas(
-                b8, jnp.asarray(m8), jnp.asarray(mu8), windows,
-                block_b=block_b, interpret=interp, reduce_impl=impl,
-                **mont_args)[:bsz]
-            return _to_radix16(out8, L16)
-        return _cached_jit(
-            (pack.m_int, "pallas", "modexp_fixed", block_b, impl, e),
-            body)(base16)
-    raise ValueError(f"unknown backend {backend!r}")
+    def body(base16):
+        b8 = _to_radix8(base16, L8)
+        win_arr = jnp.asarray(windows, jnp.int32).reshape(1, -1)
+        if impl == "montgomery":
+            out8 = mg.modexp2d_mont_fixed(
+                b8, win_arr, jnp.asarray(m8), pack.mp8,
+                jnp.asarray(pack.r1_8), jnp.asarray(pack.r2_8))
+        else:
+            out8 = mg.modexp2d_fixed_barrett(
+                b8, win_arr, jnp.asarray(m8), jnp.asarray(mu8))
+        return _to_radix16(out8, L16)
+    return _cached_jit((pack.m_int, "modexp_fixed", impl, e), body)(base16)
 
 
 # fixed CRT ladder launches by form, bumped by the callers that launch them
 # (``core.paillier_batch``, ``core.paillier_vec``): "stacked" runs both
-# halves as one ladder, "split" as two ladders in a row
+# halves as one ladder, "split" as two ladders in a row (the Barrett oracle)
 FIXED_CRT = {"stacked": 0, "split": 0}
 
 
 def fixed_pair_stacks(pack_p: ModulusPack, pack_q: ModulusPack,
-                      backend: str | None = None,
                       reduce_impl: str | None = None) -> bool:
     """Whether :func:`modexp_fixed_pair` runs its halves as one stacked
-    ladder: on the ``ref`` backend with both moduli on the Montgomery
-    path.  Pallas keeps two calls (it does not lower on the TPU), and so
-    does Barrett, the oracle."""
-    return ((backend or DEFAULT_BACKEND) == "ref"
-            and _resolve_reduce(pack_p, reduce_impl) == "montgomery"
+    ladder: when both moduli are on the Montgomery path.  Barrett, the
+    oracle, keeps two calls."""
+    return (_resolve_reduce(pack_p, reduce_impl) == "montgomery"
             and _resolve_reduce(pack_q, reduce_impl) == "montgomery")
 
 
-def count_fixed_crt(pack_p: ModulusPack, pack_q: ModulusPack,
-                    backend: str | None = None) -> None:
+def count_fixed_crt(pack_p: ModulusPack, pack_q: ModulusPack) -> None:
     """Count one launch of a fixed CRT pair in :data:`FIXED_CRT`."""
-    FIXED_CRT["stacked" if fixed_pair_stacks(pack_p, pack_q, backend)
+    FIXED_CRT["stacked" if fixed_pair_stacks(pack_p, pack_q)
               else "split"] += 1
 
 
@@ -355,7 +274,6 @@ def _pair_constants(m_p: int, m_q: int, L8: int) -> tuple:
 
 def modexp_fixed_pair(bp16: jax.Array, ep: int, pack_p: ModulusPack,
                       bq16: jax.Array, eq: int, pack_q: ModulusPack,
-                      backend: str | None = None,
                       reduce_impl: str | None = None
                       ) -> tuple[jax.Array, jax.Array]:
     """(bp^ep mod p, bq^eq mod q) over two batches of the same size: the
@@ -374,11 +292,9 @@ def modexp_fixed_pair(bp16: jax.Array, ep: int, pack_p: ModulusPack,
     Exponents are key-constant, as for :func:`modexp_fixed` (the jit
     cache is keyed on them).
     """
-    if not fixed_pair_stacks(pack_p, pack_q, backend, reduce_impl):
-        return (modexp_fixed(bp16, ep, pack_p, backend=backend,
-                             reduce_impl=reduce_impl),
-                modexp_fixed(bq16, eq, pack_q, backend=backend,
-                             reduce_impl=reduce_impl))
+    if not fixed_pair_stacks(pack_p, pack_q, reduce_impl):
+        return (modexp_fixed(bp16, ep, pack_p, reduce_impl=reduce_impl),
+                modexp_fixed(bq16, eq, pack_q, reduce_impl=reduce_impl))
     if bp16.shape[0] == 0:
         return (jnp.zeros((0, pack_p.L16), jnp.int32),
                 jnp.zeros((0, pack_q.L16), jnp.int32))
@@ -402,8 +318,8 @@ def modexp_fixed_pair(bp16: jax.Array, ep: int, pack_p: ModulusPack,
         out16 = _to_radix16(out8, L16)
         return out16[:n, :pack_p.L16], out16[n:, :pack_q.L16]
 
-    return _cached_jit((pack_p.m_int, pack_q.m_int, "ref",
-                        "modexp_fixed_pair", ep, eq), body)(bp16, bq16)
+    return _cached_jit((pack_p.m_int, pack_q.m_int, "modexp_fixed_pair",
+                        ep, eq), body)(bp16, bq16)
 
 
 # ---------------------------------------------------------------------------

@@ -1,10 +1,8 @@
-"""Pure-jnp oracle for the Pallas crypto kernels.
-
-Two independent references:
+"""The jnp ladders behind ``kernels/ops.py``, and the paper's FFT multiply.
 
 1. ``mulmod_ref`` / ``modexp_ref`` — the radix-256 primitives from
-   ``kernels/common.py`` executed as ordinary traced jnp (no pallas_call).
-   Bit-identical math to the kernels (they share helpers), exercised against
+   ``kernels/common.py`` and ``kernels/montgomery.py``, chosen by ladder
+   and reduction, as ordinary traced jnp; exercised against
    ``core.bigint`` (radix-2^16 / int64) and Python ints in tests.
 
 2. ``fft_mul_ref`` — the paper's own FFT polynomial multiplication

@@ -26,10 +26,9 @@ Record kinds (``LEDGER_SCHEMA_VERSION`` guards the envelope):
 
 The environment fingerprint (``env_fingerprint``) records what the
 numbers were measured ON: ``runtime.dispatch.device_kind()`` (jax
-backend + chip count), the active ``REPRO_REDUCE_IMPL`` /
-``REPRO_MODEXP_METHOD`` ladder knobs, jax/numpy versions, the git
-commit, and the Python version — the axes along which a perf baseline
-stops being comparable.
+backend + chip count), the active ``REPRO_REDUCE_IMPL`` ladder knob,
+jax/numpy versions, the git commit, and the Python version — the axes
+along which a perf baseline stops being comparable.
 
 Query helpers (:func:`load`, :func:`query`, :func:`baseline_for`) are
 what :mod:`repro.obs.sentinel` and ``scripts/check_regression.py`` build
@@ -107,7 +106,6 @@ def env_fingerprint() -> dict:
     return {
         "device": device,
         "reduce_impl": os.environ.get("REPRO_REDUCE_IMPL", "montgomery"),
-        "modexp_method": os.environ.get("REPRO_MODEXP_METHOD"),
         "jax": jax_version,
         "numpy": np.__version__,
         "python": ".".join(map(str, sys.version_info[:3])),

@@ -66,7 +66,7 @@ _MATVEC_JIT_MAX = 32   # FIFO-bounded: sweeps over many keys/shapes must
 
 
 def c_matvec_many(vk, Ks: jnp.ndarray, cs: jnp.ndarray,
-                  exp_limbs: int = 4, backend: str | None = None):
+                  exp_limbs: int = 4):
     """Batched homomorphic matvec: out[b, i] = prod_j cs[b, j]^{Ks[b,i,j]}.
 
     The (B, M, N) exponent block becomes a single flattened ModExp launch
@@ -80,11 +80,11 @@ def c_matvec_many(vk, Ks: jnp.ndarray, cs: jnp.ndarray,
         bases = jnp.broadcast_to(cs[:, None, :, :], (B, M, N, L2))
         powed = ops.modexp(bases.reshape(B * M * N, L2),
                            pv.int64_to_limbs(Ks.reshape(-1), exp_limbs),
-                           vk.pack_n2, backend=backend)
-        out = pv.mul_tree(vk, powed.reshape(B * M, N, L2), backend=backend)
+                           vk.pack_n2)
+        out = pv.mul_tree(vk, powed.reshape(B * M, N, L2))
         return out.reshape(B, M, L2)
 
-    key = (id(vk), "cmv_many", backend, exp_limbs, (B, M, N))
+    key = (id(vk), "cmv_many", exp_limbs, (B, M, N))
     fn = _MATVEC_JIT.get(key)
     if fn is None:
         import jax
@@ -382,8 +382,7 @@ class CoalesceQueue:
                 self.counter.bump("modexp", B * M * N)
                 self.counter.bump("mulmod", B * M * (N - 1))
             return pbatch.matvec_many(self.box.batch_key(), Ks,
-                                      [e.args[1] for e in entries],
-                                      backend=self.box.kernel_backend)
+                                      [e.args[1] for e in entries])
         # one fused launch for all same-shaped (M, N) blocks
         vk = self.box.vk
         Ks = jnp.stack([jnp.asarray(np.asarray(e.args[0], np.int64))
@@ -393,7 +392,7 @@ class CoalesceQueue:
         if self.counter is not None:  # same totals box.matvec would bump
             self.counter.bump("modexp", B * M * N)
             self.counter.bump("mulmod", B * M * (N - 1))
-        out = c_matvec_many(vk, Ks, cs, backend=self.box.backend)
+        out = c_matvec_many(vk, Ks, cs)
         return [out[i] for i in range(B)]
 
 

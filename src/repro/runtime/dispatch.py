@@ -399,8 +399,7 @@ class AdaptiveBox:
     name = "auto"
 
     def __init__(self, key: gold.PaillierKey, rng: random.Random,
-                 table: dict, counter=None, kernel_backend: str | None = None,
-                 plain_bits: int | None = None):
+                 table: dict, counter=None, plain_bits: int | None = None):
         from ..core import protocol  # deferred: avoids import cycle
         self.key = key
         self.table = table
@@ -411,9 +410,8 @@ class AdaptiveBox:
             "gold": self.gold,
             "gold_batch": protocol.GoldBox(
                 key, rng, crt=True, counter=self.counter, batch=True,
-                batch_min=1, kernel_backend=kernel_backend),
-            "vec": protocol.VecBox(key, rng, backend=kernel_backend,
-                                   counter=self.counter,
+                batch_min=1),
+            "vec": protocol.VecBox(key, rng, counter=self.counter,
                                    plain_bits=plain_bits),
         }
         self.vec = self.boxes["vec"]

@@ -233,7 +233,7 @@ class MasterActor:
             if cfg.collaborative and rt.key is not None:
                 rt.transport.send(MASTER, edge_name(k), "collab",
                                   (rt.key.p2, rt.key.phi_p2, rt.key.g,
-                                   cfg.gold_batch, cfg.kernel_backend))
+                                   cfg.gold_batch))
             Qk, mu, scale = self.edge_setups[k]
             rt.transport.send(MASTER, edge_name(k), "init",
                               (Qk, mu, scale), nbytes=Qk.nbytes)
@@ -740,7 +740,6 @@ def build_runtime(A: np.ndarray, y: np.ndarray,
             backends=("gold", "gold_batch", "vec"), path=calib_path,
             warm_key=key, warm_shapes=(nk, (1, nk, nk)))
         box = dispatch.AdaptiveBox(key, rng, table, counter=counter,
-                                   kernel_backend=cfg.kernel_backend,
                                    plain_bits=cfg.spec.plaintext_bits(nk))
     else:
         box, key = protocol.make_box(cfg, nk, rng, counter)

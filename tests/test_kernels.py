@@ -1,5 +1,6 @@
-"""Pallas kernel validation: interpret-mode vs ref.py oracle vs Python ints,
-swept over modulus sizes (incl. odd byte lengths), batch shapes and backends.
+"""Limb kernel validation: the ``kernels/ops.py`` wrappers vs Python ints,
+swept over modulus sizes (incl. odd byte lengths), batch shapes and
+reductions.
 """
 import random
 
@@ -19,9 +20,11 @@ def _mk_modulus(bits):
     return RNG.getrandbits(bits) | (1 << (bits - 1)) | 1
 
 
-@pytest.mark.parametrize("bits", [24, 48, 56, 64, 96, 120, 160])
-@pytest.mark.parametrize("backend", ["ref", "pallas"])
-def test_mulmod_sweep(bits, backend):
+SWEEP_BITS = [24, 48, 56, 64, 96, 120, 160]
+
+
+@pytest.mark.parametrize("bits", SWEEP_BITS)
+def test_mulmod_sweep(bits):
     m = _mk_modulus(bits)
     pack = ops.pack_modulus(m)
     B = 7
@@ -29,13 +32,12 @@ def test_mulmod_sweep(bits, backend):
     b = [RNG.randrange(m) for _ in range(B)]
     A = jnp.asarray(bi.from_ints(a, pack.L16))
     Bv = jnp.asarray(bi.from_ints(b, pack.L16))
-    got = bi.to_ints(ops.mulmod(A, Bv, pack, backend=backend))
+    got = bi.to_ints(ops.mulmod(A, Bv, pack))
     assert got == [(x * y) % m for x, y in zip(a, b)]
 
 
 @pytest.mark.parametrize("bits", [32, 64, 96])
-@pytest.mark.parametrize("backend", ["ref", "pallas"])
-def test_modexp_sweep(bits, backend):
+def test_modexp_sweep(bits):
     m = _mk_modulus(bits)
     pack = ops.pack_modulus(m)
     B = 5
@@ -43,22 +45,38 @@ def test_modexp_sweep(bits, backend):
     e = [RNG.randrange(1 << 24) for _ in range(B)]
     A = jnp.asarray(bi.from_ints(a, pack.L16))
     E = jnp.asarray(bi.from_ints(e, 2))
-    got = bi.to_ints(ops.modexp(A, E, pack, backend=backend))
+    got = bi.to_ints(ops.modexp(A, E, pack))
     assert got == [pow(x, ee, m) for x, ee in zip(a, e)]
 
 
-@pytest.mark.parametrize("block_b", [1, 2, 8])
-def test_pallas_block_shapes(block_b):
-    """BlockSpec grid correctness across batch paddings."""
-    m = _mk_modulus(64)
+@pytest.mark.parametrize("bits", SWEEP_BITS)
+def test_modexp_fixed_sweep(bits):
+    """The fixed-exponent ladder, both reductions, against ``pow()``."""
+    m = _mk_modulus(bits)
     pack = ops.pack_modulus(m)
-    B = 5   # deliberately not a multiple of block_b
-    a = [RNG.randrange(m) for _ in range(B)]
-    b = [RNG.randrange(m) for _ in range(B)]
-    got = bi.to_ints(ops.mulmod(jnp.asarray(bi.from_ints(a, pack.L16)),
-                                jnp.asarray(bi.from_ints(b, pack.L16)),
-                                pack, backend="pallas", block_b=block_b))
-    assert got == [(x * y) % m for x, y in zip(a, b)]
+    a = [RNG.randrange(m) for _ in range(5)]
+    e = RNG.getrandbits(bits) | 1
+    A = jnp.asarray(bi.from_ints(a, pack.L16))
+    for impl in ("barrett", "montgomery"):
+        got = bi.to_ints(ops.modexp_fixed(A, e, pack, reduce_impl=impl))
+        assert got == [pow(x, e, m) for x in a], impl
+
+
+@pytest.mark.parametrize("bits", SWEEP_BITS)
+def test_modexp_fixed_pair_sweep(bits):
+    """Both halves as one stacked ladder, q a byte narrower than p."""
+    p, q = _mk_modulus(bits), _mk_modulus(bits - 8)
+    pp, pq = ops.pack_modulus(p), ops.pack_modulus(q)
+    assert ops.fixed_pair_stacks(pp, pq, "montgomery")
+    a = [RNG.randrange(p) for _ in range(5)]
+    b = [RNG.randrange(q) for _ in range(5)]
+    ep, eq = RNG.getrandbits(bits) | 1, RNG.getrandbits(bits - 8) | 1
+    xp, xq = ops.modexp_fixed_pair(
+        jnp.asarray(bi.from_ints(a, pp.L16)), ep, pp,
+        jnp.asarray(bi.from_ints(b, pq.L16)), eq, pq,
+        reduce_impl="montgomery")
+    assert bi.to_ints(xp) == [pow(x, ep, p) for x in a]
+    assert bi.to_ints(xq) == [pow(x, eq, q) for x in b]
 
 
 def test_radix_conversions_roundtrip():
@@ -170,7 +188,6 @@ def test_kernel_vs_gold_paillier_roundtrip():
     ms = [123456, 42, 10**9]
     pool = gold.make_r_pool(key, len(ms), random.Random(6))
     rn = jnp.asarray(bi.from_ints(pool, vk.pack_n2.L16))
-    c = pv.encrypt_batch(vk, jnp.asarray(ms, jnp.int64), rn,
-                         backend="pallas")
+    c = pv.encrypt_batch(vk, jnp.asarray(ms, jnp.int64), rn)
     for m, ci in zip(ms, bi.to_ints(c)):
         assert gold.decrypt(key, ci) == m

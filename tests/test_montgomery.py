@@ -32,8 +32,6 @@ from repro.kernels import ops
 settings.register_profile("ci", max_examples=4, deadline=None)
 settings.load_profile("ci")
 
-BLOCK = 128
-
 
 def _edge_moduli(bits: int) -> list[int]:
     """Top-limb edge cases: all-ones (0xFF top limb) and minimal top limb
@@ -65,8 +63,7 @@ def test_modexp_mont_vs_barrett_vs_gold_across_key_sizes(seed):
             b16 = _limbs(bases, pack.L16)
             e16 = _limbs(exps, 2)
             for impl in ("barrett", "montgomery"):
-                got = bi.to_ints(ops.modexp(b16, e16, pack, backend="ref",
-                                            reduce_impl=impl))
+                got = bi.to_ints(ops.modexp(b16, e16, pack, reduce_impl=impl))
                 assert got == want, (bits, impl, pack.m_int)
 
 
@@ -80,28 +77,32 @@ def test_modexp_fixed_vs_both_impls_and_gold(seed):
     for e in (0, 1, rng.randrange(1 << 60)):
         want = [pow(b, e, pack.m_int) for b in bases]
         for impl in ("barrett", "montgomery"):
-            got = bi.to_ints(ops.modexp_fixed(b16, e, pack, backend="ref",
-                                              reduce_impl=impl))
+            got = bi.to_ints(ops.modexp_fixed(b16, e, pack, reduce_impl=impl))
             assert got == want, (e, impl)
 
 
 @pytest.mark.parametrize("B", [0, 1, 5])
-def test_batch_shapes_through_pallas(B):
-    """B in {0, 1, non-block-multiple} through the padded pallas path."""
-    pack = PACKS[256][2]
+def test_batch_shapes_through_ops(B):
+    """B in {0, 1, 5} through every per-key ladder wrapper of ``ops``."""
+    pack, pack_q = PACKS[256][2], PACKS[256][1]
     rng = random.Random(B)
     bases = [rng.randrange(pack.m_int) for _ in range(B)]
     exps = [rng.randrange(1 << 32) for _ in range(B)]
     b16 = _limbs(bases, pack.L16)
     e16 = _limbs(exps, 2).reshape(B, 2)
     for impl in ("barrett", "montgomery"):
-        got = bi.to_ints(ops.modexp(b16, e16, pack, backend="pallas",
-                                    reduce_impl=impl))
+        got = bi.to_ints(ops.modexp(b16, e16, pack, reduce_impl=impl))
         assert got == [pow(b, e, pack.m_int)
                        for b, e in zip(bases, exps)], (B, impl)
-    got = bi.to_ints(ops.modexp_fixed(b16, 37, pack, backend="pallas",
+    got = bi.to_ints(ops.modexp_fixed(b16, 37, pack,
                                       reduce_impl="montgomery"))
     assert got == [pow(b, 37, pack.m_int) for b in bases], B
+    ys = [rng.randrange(pack_q.m_int) for _ in range(B)]
+    xp, xq = ops.modexp_fixed_pair(b16, 37, pack,
+                                   _limbs(ys, pack_q.L16),
+                                   41, pack_q, reduce_impl="montgomery")
+    assert bi.to_ints(xp) == [pow(b, 37, pack.m_int) for b in bases], B
+    assert bi.to_ints(xq) == [pow(y, 41, pack_q.m_int) for y in ys], B
 
 
 def test_even_modulus_falls_back_to_barrett():
@@ -110,8 +111,7 @@ def test_even_modulus_falls_back_to_barrett():
     assert pack.mp8 is None
     bases = [12345, m - 1]
     got = bi.to_ints(ops.modexp(_limbs(bases, pack.L16), _limbs([7, 9], 1),
-                                pack, backend="ref",
-                                reduce_impl="montgomery"))
+                                pack, reduce_impl="montgomery"))
     assert got == [pow(12345, 7, m), pow(m - 1, 9, m)]
 
 
@@ -215,17 +215,17 @@ def test_time_montmul_script_checks_and_reports(tmp_path):
 
 def test_mulmod_cache_one_entry_across_batch_sizes():
     """Varying incoming batch sizes must NOT grow the jit-closure cache:
-    batches pad up to the canonical block and the key carries block_b,
-    never the raw batch (the pre-PR leak grew one entry per size)."""
+    the key carries the modulus and the op, never the batch (jax.jit
+    keeps one trace per shape inside that one entry)."""
     m = (1 << 192) - 237        # fresh modulus: no prior cache entries
     pack = ops.pack_modulus(m)
     before = set(ops._JIT_CACHE)
     for B in (3, 5, 17, 64, 130):
         a = _limbs([i + 1 for i in range(B)], pack.L16)
-        got = bi.to_ints(ops.mulmod(a, a, pack, backend="pallas"))
+        got = bi.to_ints(ops.mulmod(a, a, pack))
         assert got == [(i + 1) * (i + 1) % m for i in range(B)]
     new = [k for k in ops._JIT_CACHE if k not in before]
-    assert new == [(m, "pallas", "mulmod", BLOCK)]
+    assert new == [(m, "mulmod")]
 
 
 def test_modexp_rejects_unknown_method_and_width():
@@ -233,15 +233,15 @@ def test_modexp_rejects_unknown_method_and_width():
     b16 = _limbs([5], pack.L16)
     e16 = _limbs([3], 1)
     with pytest.raises(ValueError, match="unknown modexp method"):
-        ops.modexp(b16, e16, pack, backend="ref", method="win8")
+        ops.modexp(b16, e16, pack, method="win8")
     with pytest.raises(ValueError, match="unknown reduce_impl"):
-        ops.modexp(b16, e16, pack, backend="ref", reduce_impl="redc2")
+        ops.modexp(b16, e16, pack, reduce_impl="redc2")
     # the wrapper-boundary win4 width check (16-bit limbs always pass;
     # the guard protects future limb-width changes with a clear error)
     with pytest.raises(ValueError, match="multiple of 4"):
         ops._validate_method("win4", 18)
     with pytest.raises(ValueError, match="non-negative"):
-        ops.modexp_fixed(b16, -3, pack, backend="ref")
+        ops.modexp_fixed(b16, -3, pack)
     with pytest.raises(ValueError, match="negative"):
         mg.exp_windows(-1)
 
@@ -293,12 +293,12 @@ def test_modexp_fixed_pair_stacked_vs_gold(p_bits, q_bits, ep_bits, eq_bits,
     ys = [rng.randrange(pq.m_int) for _ in range(B)]
     want_p = [pow(x, ep, pp.m_int) for x in xs]
     want_q = [pow(y, eq, pq.m_int) for y in ys]
-    assert ops.fixed_pair_stacks(pp, pq, "ref", "montgomery")
-    assert not ops.fixed_pair_stacks(pp, pq, "ref", "barrett")
+    assert ops.fixed_pair_stacks(pp, pq, "montgomery")
+    assert not ops.fixed_pair_stacks(pp, pq, "barrett")
     for impl in ("montgomery", "barrett"):
         xp, xq = ops.modexp_fixed_pair(_limbs(xs, pp.L16), ep, pp,
                                        _limbs(ys, pq.L16), eq, pq,
-                                       backend="ref", reduce_impl=impl)
+                                       reduce_impl=impl)
         assert xp.shape == (B, pp.L16) and xq.shape == (B, pq.L16)
         assert bi.to_ints(xp) == want_p, impl
         assert bi.to_ints(xq) == want_q, impl

@@ -19,7 +19,6 @@ from repro.core import paillier as gold
 from repro.core import paillier_batch as pb
 from repro.core import paillier_vec as pv
 from repro.kernels import ops
-from repro.kernels.modexp import modexp_pallas
 
 HBM_BYTES = 16 * 2 ** 30      # one v5e chip
 BATCH = 64
@@ -134,15 +133,3 @@ def test_modexp_rows_n2(vk, one_chip):
              _limbs(rows, L8, one_chip), _limbs(rows, 256, one_chip),
              _limbs(rows, L8, one_chip), _limbs(rows, L8 + 1, one_chip))
 
-
-@pytest.mark.xfail(strict=True, raises=NotImplementedError,
-                   reason="the limb helpers' fori_loop + dynamic_slice have "
-                          "no Pallas TPU lowering")
-def test_modexp_pallas_lowers(vk, one_chip):
-    pack = vk.pack_p2
-    mont = dict(r1_8=pack.r1_8, r2_8=pack.r2_8, mp=pack.mp8)
-    _compile(lambda b, e: modexp_pallas(
-                 b, e, jnp.asarray(pack.m8), jnp.asarray(pack.mu8),
-                 block_b=8, interpret=False, method="win4",
-                 reduce_impl="montgomery", **mont),
-             _limbs(8, pack.L8, one_chip), _limbs(8, 8, one_chip))
